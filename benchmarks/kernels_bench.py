@@ -1,6 +1,11 @@
-"""Kernel micro-benchmarks (CPU wall time is NOT the metric — these run
-in interpret mode; the derived column reports validated max-abs error vs
-the pure-jnp oracle, plus analytic FLOPs of the TPU-target shape)."""
+"""Kernel micro-benchmarks: each Pallas kernel against its pure-jnp
+oracle (the derived column reports max-abs error, plus analytic FLOPs
+of the shape).
+
+The kernels compile for the TPU.  ``interpret=True`` runs them in the
+Pallas interpreter instead, which is how they run on the CPU; a time
+from the interpreter is not a device time, and every row names the
+device and mode it ran in."""
 from __future__ import annotations
 
 import time
@@ -22,7 +27,10 @@ def _timed(fn, *args, reps=2, **kw):
     return out, (time.perf_counter() - t0) / reps * 1e6
 
 
-def run() -> list[str]:
+def run(interpret: bool = False) -> list[str]:
+    dev = jax.devices()[0]
+    where = f"{dev.platform}:{dev.device_kind}" + (
+        ":interpret" if interpret else "")
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 6)
     rows = []
@@ -31,7 +39,7 @@ def run() -> list[str]:
     q = jax.random.normal(ks[0], (b, s, h, d), jnp.bfloat16)
     k = jax.random.normal(ks[1], (b, s, kv, d), jnp.bfloat16)
     v = jax.random.normal(ks[2], (b, s, kv, d), jnp.bfloat16)
-    out, us = _timed(ops.flash_attention, q, k, v, interpret=True)
+    out, us = _timed(ops.flash_attention, q, k, v, interpret=interpret)
     err = float(jnp.max(jnp.abs(
         out.astype(jnp.float32)
         - R.flash_attention_ref(q, k, v).astype(jnp.float32))))
@@ -43,7 +51,7 @@ def run() -> list[str]:
     kc = jax.random.normal(ks[1], (4, 2048, kv, d), jnp.bfloat16)
     vc = jax.random.normal(ks[2], (4, 2048, kv, d), jnp.bfloat16)
     out, us = _timed(ops.decode_attention, q1, kc, vc, jnp.int32(2048),
-                     interpret=True)
+                     interpret=interpret)
     err = float(jnp.max(jnp.abs(
         out.astype(jnp.float32)
         - R.decode_attention_ref(q1, kc, vc, 2048).astype(jnp.float32))))
@@ -51,7 +59,7 @@ def run() -> list[str]:
 
     x = jax.random.normal(ks[3], (8, 128, 256), jnp.bfloat16)
     w = jax.random.normal(ks[4], (8, 256, 512), jnp.bfloat16)
-    out, us = _timed(ops.moe_gemm, x, w, interpret=True)
+    out, us = _timed(ops.moe_gemm, x, w, interpret=interpret)
     ref = R.moe_gemm_ref(x, w)
     rel = float(jnp.max(jnp.abs(out.astype(jnp.float32)
                                 - ref.astype(jnp.float32)))
@@ -64,7 +72,7 @@ def run() -> list[str]:
     cc = jax.random.normal(ks[2], (bsz, s2, n))
     dt = jax.nn.softplus(jax.random.normal(ks[3], (bsz, s2, hh)))
     (y, fin), us = _timed(ops.mamba2_scan, xh, bb, cc, dt,
-                          jnp.zeros(hh), chunk=64, interpret=True)
+                          jnp.zeros(hh), chunk=64, interpret=interpret)
     yr, _ = R.mamba2_scan_ref(xh, bb, cc, dt, jnp.zeros(hh))
     rows.append(f"kernel/mamba2_scan,{us:.1f},"
                 f"err={float(jnp.max(jnp.abs(y - yr))):.1e}")
@@ -75,10 +83,11 @@ def run() -> list[str]:
     w6 = jax.nn.sigmoid(jax.random.normal(ks[3], (1, 128, 2, 32)))
     bonus = jax.random.normal(ks[4], (2, 32)) * 0.1
     (out, fin), us = _timed(ops.rwkv6_scan, r, kk, vv, w6, bonus,
-                            chunk=32, interpret=True)
+                            chunk=32, interpret=interpret)
     outr, _ = R.rwkv6_scan_ref(r, kk, vv, w6, bonus)
     rows.append(f"kernel/rwkv6_scan,{us:.1f},"
                 f"err={float(jnp.max(jnp.abs(out - outr))):.1e}")
+    rows = [f"{row};device={where}" for row in rows]
     for row in rows:
         print(row)
     return rows

@@ -6,6 +6,9 @@ and writes per-experiment CSVs under results/workflow.
     PYTHONPATH=src python -m benchmarks.run              # everything
     PYTHONPATH=src python -m benchmarks.run --only table1,table12
     PYTHONPATH=src python -m benchmarks.run --quick      # small slices
+    PYTHONPATH=src python -m benchmarks.run --interpret  # kernels on CPU
+
+Exits nonzero when any phase raised.
 """
 from __future__ import annotations
 
@@ -21,6 +24,9 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     ap.add_argument("--quick", action="store_true")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas kernels in the interpreter "
+                         "(needed on the CPU backend)")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
@@ -36,10 +42,11 @@ def main() -> None:
         "table11": tables.table11_perturbation,
         "table12": tables.table12_solver,
         "fig2": tables.fig2_ecdf,
-        "kernels": kernels_bench.run,
+        "kernels": lambda: kernels_bench.run(interpret=args.interpret),
         "roofline": _roofline_summary,
     }
     all_rows: list[str] = []
+    failed: list[str] = []
     t_start = time.time()
     for name, fn in benches.items():
         if only and name not in only:
@@ -49,14 +56,17 @@ def main() -> None:
             rows = fn() or []
             all_rows.extend(rows)
             print(f"[{name} done in {time.time()-t0:.1f}s]")
-        except Exception as e:   # keep the harness running
+        except Exception as e:   # run the other phases, then fail
             import traceback
             traceback.print_exc()
             all_rows.append(f"{name}/ERROR,0,{type(e).__name__}")
+            failed.append(name)
     print("\n# CSV (name,us_per_call,derived)")
     for row in all_rows:
         print(row)
     print(f"# total wall time {time.time()-t_start:.1f}s")
+    if failed:
+        sys.exit(f"phases failed: {', '.join(failed)}")
 
 
 def _roofline_summary() -> list[str]:

@@ -7,6 +7,9 @@ prefill + autoregressive decode per stage, model residency switches,
 and prefix-cache reuse on the serving engine.
 
     PYTHONPATH=src python examples/serve_workflow.py
+
+It runs on JAX's default backend; ``chip_smoke.py`` serves the same
+DAG at full qwen3-1.7b width on a TPU.
 """
 import dataclasses
 import sys
@@ -21,11 +24,13 @@ from repro.configs.archs import SMOKE                        # noqa: E402
 from repro.core.devices import homogeneous_cluster           # noqa: E402
 from repro.core.executor import fresh_state                  # noqa: E402
 from repro.core.policies import make_policy                  # noqa: E402
-from repro.core.workflow import Stage, Workflow              # noqa: E402
+from repro.jax_cache import use_compile_cache                # noqa: E402
 from repro.serving.engine import ModelBundle, ServingEngine  # noqa: E402
+from repro.workflowbench.suites import agentic_workflow      # noqa: E402
 
 
 def main() -> None:
+    use_compile_cache()
     cfg_a = SMOKE["qwen3-1.7b"]
     cfg_b = dataclasses.replace(SMOKE["glm4-9b"],
                                 vocab_size=cfg_a.vocab_size)
@@ -34,20 +39,7 @@ def main() -> None:
         "qwen-7b": ModelBundle.create("qwen-7b", cfg_a, seed=0),
         "llama-8b": ModelBundle.create("llama-8b", cfg_b, seed=1),
     }
-    stages = {
-        "retrieve": Stage("retrieve", "qwen-7b", base_cost={-1: 0.01},
-                          prefix_group="ctx", max_shards=2,
-                          output_tokens=128),
-        "work_a": Stage("work_a", "llama-8b", base_cost={-1: 0.02},
-                        parents=("retrieve",), output_tokens=256),
-        "work_b": Stage("work_b", "qwen-7b", base_cost={-1: 0.02},
-                        prefix_group="ctx", parents=("retrieve",),
-                        output_tokens=256),
-        "merge": Stage("merge", "qwen-7b", base_cost={-1: 0.015},
-                       prefix_group="ctx",
-                       parents=("work_a", "work_b")),
-    }
-    wf = Workflow(wid="agentic-demo", stages=stages, num_queries=8)
+    wf = agentic_workflow("agentic-demo", num_queries=8)
 
     engine = ServingEngine(bundles, n_devices=2, gen_len=6, prompt_len=16)
     state = fresh_state(homogeneous_cluster(2))

@@ -3,7 +3,9 @@
 Every assigned architecture gets one module in this package exporting
 ``CONFIG`` (the exact published configuration) and ``SMOKE_CONFIG`` (a
 reduced same-family configuration for CPU smoke tests).  The full
-configs are exercised only via the AOT dry-run (ShapeDtypeStruct — no
+qwen3-1.7b config is served on a TPU by ``chip_smoke.py`` and
+AOT-compiled for a described v5e by ``tests/test_tpu_compile.py``; the
+others are exercised only via the AOT dry-run (ShapeDtypeStruct — no
 allocation).
 """
 from __future__ import annotations

@@ -3,7 +3,8 @@
 Grid (B·KV, nS) with the cache-length dimension sequential; the running
 (m, l, acc) state for all G query heads of the KV group sits in VMEM
 scratch.  Invalid cache positions (≥ cache_len) are masked, so the same
-kernel serves any fill level of a static cache.
+kernel serves any fill level of a static cache.  ``cache_len`` reaches
+the kernel as a scalar-prefetch operand in SMEM.
 """
 from __future__ import annotations
 
@@ -13,8 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -70,27 +69,29 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     qh = q.reshape(b, kv, g, d).reshape(b * kv, g, d)
     kh = k_cache.transpose(0, 2, 1, 3).reshape(b * kv, s, d)
     vh = v_cache.transpose(0, 2, 1, 3).reshape(b * kv, s, d)
-    lens = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (b * kv,))
+    lens = jnp.asarray(cache_len, jnp.int32).reshape(1)
 
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, block_s=block_s,
-                          scale=d ** -0.5),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b * kv, ns),
         in_specs=[
-            pl.BlockSpec((1,), lambda bh, si: (bh,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, g, d), lambda bh, si: (bh, 0, 0)),
-            pl.BlockSpec((1, block_s, d), lambda bh, si: (bh, si, 0)),
-            pl.BlockSpec((1, block_s, d), lambda bh, si: (bh, si, 0)),
+            pl.BlockSpec((1, g, d), lambda bh, si, lens: (bh, 0, 0)),
+            pl.BlockSpec((1, block_s, d), lambda bh, si, lens: (bh, si, 0)),
+            pl.BlockSpec((1, block_s, d), lambda bh, si, lens: (bh, si, 0)),
         ],
-        out_specs=pl.BlockSpec((1, g, d), lambda bh, si: (bh, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * kv, g, d), q.dtype),
+        out_specs=pl.BlockSpec((1, g, d), lambda bh, si, lens: (bh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+    )
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, block_s=block_s,
+                          scale=d ** -0.5),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b * kv, g, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lens, qh, kh, vh)

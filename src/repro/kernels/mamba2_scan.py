@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _mamba_kernel(xh_ref, b_ref, c_ref, dta_ref, dt_ref, o_ref, fin_ref,
                   state_scr, *, chunk: int):
@@ -34,9 +32,13 @@ def _mamba_kernel(xh_ref, b_ref, c_ref, dta_ref, dt_ref, o_ref, fin_ref,
     dta = dta_ref[0].astype(jnp.float32)     # [L, 1]  (dt * a, <= 0)
     dt = dt_ref[0].astype(jnp.float32)       # [L, 1]
 
-    cum = jnp.cumsum(dta, axis=0)            # [L, 1]
     li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive prefix sum as a lower-triangular ones matmul (Pallas TPU
+    # has no cumsum lowering)
+    cum = jax.lax.dot((li >= lj).astype(jnp.float32), dta,
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)    # [L, 1]
     # intra-chunk: y_i += sum_{j<=i} exp(cum_i - cum_j) dt_j (c_i·b_j) x_j
     decay = jnp.where(li >= lj, jnp.exp(cum - cum.T), 0.0)   # [L, L]
     sb = jax.lax.dot_general(cc, bb, (((1,), (1,)), ((), ())),
@@ -49,12 +51,12 @@ def _mamba_kernel(xh_ref, b_ref, c_ref, dta_ref, dt_ref, o_ref, fin_ref,
         cc, state, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     # state update: state = exp(total) * state + sum_j exp(total-cum_j) dt_j x_j b_j^T
-    total = cum[chunk - 1]
-    tail = jnp.exp(total[None] - cum) * dt                   # [L, 1]
+    total = jnp.sum(dta)                                     # scalar
+    tail = jnp.exp(total - cum) * dt                         # [L, 1]
     st_new = jax.lax.dot_general(x, bb * tail,
                                  (((0,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    state_scr[...] = state * jnp.exp(total)[None] + st_new
+    state_scr[...] = state * jnp.exp(total) + st_new
 
     o_ref[0] = y.astype(o_ref.dtype)
 
@@ -101,7 +103,7 @@ def mamba2_scan(xh: jax.Array, b: jax.Array, c: jax.Array, dt: jax.Array,
             jax.ShapeDtypeStruct((bsz * h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xf, bf, cf, dtaf, dtf)

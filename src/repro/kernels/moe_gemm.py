@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _moe_kernel(x_ref, w_ref, o_ref, acc_scr):
     di = pl.program_id(3)
@@ -60,7 +58,7 @@ def moe_gemm(x: jax.Array, w: jax.Array, *, block_c: int = 128,
                                lambda ei, ci, fi, di: (ei, ci, fi)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,12 +1,12 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels run in interpret mode (the TPU target
-is validated structurally); pass ``interpret=False`` on real TPUs.
-``REPRO_KERNEL_INTERPRET=0`` flips the default.
+The kernels compile for the TPU by default.  On the CPU backend, which
+has no Pallas TPU lowering, callers pass ``interpret=True`` explicitly
+(as the kernel tests do); nothing switches to interpret mode on its
+own.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -17,13 +17,11 @@ from repro.kernels import moe_gemm as _mg
 from repro.kernels import mamba2_scan as _ms
 from repro.kernels import rwkv6_scan as _rs
 
-_DEFAULT_INTERPRET = os.environ.get("REPRO_KERNEL_INTERPRET", "1") == "1"
-
 
 @partial(jax.jit, static_argnames=("causal", "window", "block_q",
                                    "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=0, block_q=128,
-                    block_k=128, interpret=_DEFAULT_INTERPRET):
+                    block_k=128, interpret=False):
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                block_q=block_q, block_k=block_k,
                                interpret=interpret)
@@ -31,7 +29,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, block_q=128,
 
 @partial(jax.jit, static_argnames=("block_s", "interpret"))
 def decode_attention(q, k_cache, v_cache, cache_len, *, block_s=256,
-                     interpret=_DEFAULT_INTERPRET):
+                     interpret=False):
     return _da.decode_attention(q, k_cache, v_cache, cache_len,
                                 block_s=block_s, interpret=interpret)
 
@@ -39,20 +37,20 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, block_s=256,
 @partial(jax.jit, static_argnames=("block_c", "block_f", "block_d",
                                    "interpret"))
 def moe_gemm(x, w, *, block_c=128, block_f=128, block_d=256,
-             interpret=_DEFAULT_INTERPRET):
+             interpret=False):
     return _mg.moe_gemm(x, w, block_c=block_c, block_f=block_f,
                         block_d=block_d, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
 def mamba2_scan(xh, b, c, dt, a_log, *, chunk=128,
-                interpret=_DEFAULT_INTERPRET):
+                interpret=False):
     return _ms.mamba2_scan(xh, b, c, dt, a_log, chunk=chunk,
                            interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
 def rwkv6_scan(r, k, v, w, bonus, *, chunk=32,
-               interpret=_DEFAULT_INTERPRET):
+               interpret=False):
     return _rs.rwkv6_scan(r, k, v, w, bonus, chunk=chunk,
                           interpret=interpret)

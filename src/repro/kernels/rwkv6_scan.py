@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _rwkv_kernel(r_ref, k_ref, v_ref, lw_ref, bonus_ref, o_ref, fin_ref,
                  state_scr, *, chunk: int):
@@ -27,34 +25,46 @@ def _rwkv_kernel(r_ref, k_ref, v_ref, lw_ref, bonus_ref, o_ref, fin_ref,
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
+    d = r_ref.shape[-1]
     r = r_ref[0].astype(jnp.float32)         # [L, K]
     k = k_ref[0].astype(jnp.float32)         # [L, K]
     v = v_ref[0].astype(jnp.float32)         # [L, V]
     lw = lw_ref[0].astype(jnp.float32)       # [L, K] log decay (<= 0)
     bonus = bonus_ref[0].astype(jnp.float32)  # [1, K] -> [K]
 
-    cum = jnp.cumsum(lw, axis=0)             # [L, K]
+    li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive prefix sum as a lower-triangular ones matmul (Pallas TPU
+    # has no cumsum lowering)
+    cum = jax.lax.dot((li >= lj).astype(jnp.float32), lw,
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)    # [L, K]
     # inter-chunk: out_i += (r_i ⊙ prod_{s<i} w_s) @ state
     dec_in = jnp.exp(cum - lw)               # [L, K]
     out = jax.lax.dot(r * dec_in, state_scr[...],
                       preferred_element_type=jnp.float32)
     # intra-chunk, strict lower triangle: pairwise exponents <= 0
     dij = (cum - lw)[:, None, :] - cum[None, :, :]      # [L, L, K]
-    li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    strict = (li > lj)[:, :, None]
+    strict = (jax.lax.broadcasted_iota(jnp.int32, dij.shape, 0)
+              > jax.lax.broadcasted_iota(jnp.int32, dij.shape, 1))
     pair = jnp.where(strict, jnp.exp(jnp.minimum(dij, 0.0)), 0.0)
-    scores = jnp.einsum("ik,ijk,jk->ij", r, pair, k)
+    scores = jnp.sum(r[:, None, :] * pair * k[None, :, :], axis=-1)
     out += jax.lax.dot(scores, v, preferred_element_type=jnp.float32)
     # diagonal bonus
     diag = jnp.sum(r * bonus * k, axis=1, keepdims=True)  # [L, 1]
     out += diag * v
     # state update
-    total = cum[chunk - 1]                               # [K]
-    tail = jnp.exp(total[None] - cum)                    # [L, K]
+    total = cum[chunk - 1:]                              # [1, K]
+    tail = jnp.exp(total - cum)                          # [L, K]
     st_new = jax.lax.dot_general(k * tail, v, (((0,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    state_scr[...] = state_scr[...] * jnp.exp(total)[:, None] + st_new
+    # state rows decay by exp(total): diag(exp(total)) @ state
+    ki = jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
+    kj = jax.lax.broadcasted_iota(jnp.int32, (d, d), 1)
+    decay = jnp.where(ki == kj, jnp.exp(total), 0.0)     # [K, K]
+    state_scr[...] = jax.lax.dot(
+        decay, state_scr[...], precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32) + st_new
 
     o_ref[0] = out.astype(o_ref.dtype)
 
@@ -101,7 +111,7 @@ def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             jax.ShapeDtypeStruct((b * h, d, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rf, kf, vf, lwf, bonus_f)

@@ -4,8 +4,10 @@ and cache-based decode paths.
 The chunked implementation keeps the materialized score block bounded at
 ``[B, H, q_chunk, kv_chunk]`` regardless of sequence length — this is the
 XLA-path equivalent of the Pallas flash kernel in ``repro.kernels`` and
-is what the multi-pod dry-run lowers (Pallas cannot compile for the CPU
-backend; the kernels are validated separately in interpret mode).
+is what the models run.  The Pallas kernels are not on the model path
+yet; they are tested against ``repro.kernels.ref`` in interpret mode on
+the CPU and AOT-compiled for a described v5e
+(``tests/test_tpu_compile.py``).
 """
 from __future__ import annotations
 

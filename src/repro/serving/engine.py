@@ -3,17 +3,18 @@
 The benchmark substrate (repro.core.executor) evaluates scheduling
 policies on proxy costs — the paper's own methodology.  This engine is
 the production path: FATE's placements drive actual model execution on
-virtual devices, each holding resident model params and per-group
-recurrent/KV prefix state.  Model residency switches move real param
-trees; prefix reuse restores a saved cache; stage execution runs real
-prefill + decode steps.  Measured wall times feed back into the
-execution state, so the scheduler sees real (not proxy) τ.
+virtual devices, each bound to a chip and holding resident model params
+and per-group recurrent/KV prefix state.  Model residency switches place
+real param trees on the chip; prefix reuse is tracked per device; stage
+execution runs real prefill + decode steps.  Wall times, read once the
+generated tokens are ready, feed back into the execution state, so the
+scheduler sees measured (not proxy) τ.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +50,23 @@ def calibrated_switch_sleep(profile: ModelProfile,
     return profile.switch_cost * p.switch_scale * time_scale
 
 
+def jit_steps(model) -> tuple[Callable, Callable]:
+    """The engine's jitted ``(prefill, decode_step)`` for ``model``.
+
+    Both run on the device their committed arguments live on, so one
+    pair serves every chip a bundle is placed on.
+    """
+    @jax.jit
+    def prefill_fn(params, tokens, cache):
+        return model.prefill(params, tokens, cache)
+
+    @jax.jit
+    def decode_fn(params, token, cache, pos):
+        return model.decode_step(params, token, cache, pos)
+
+    return prefill_fn, decode_fn
+
+
 @dataclasses.dataclass
 class ModelBundle:
     """A servable model: config + weights + step functions."""
@@ -57,41 +75,91 @@ class ModelBundle:
     params: Any
     prefill: Callable
     decode: Callable
+    model: Any = None
+    init: Optional[Callable] = None     # jitted ``model.init``
 
     @classmethod
     def create(cls, name: str, cfg, seed: int = 0) -> "ModelBundle":
-        """Build and initialize the model, jit its prefill/decode step
-        functions, and return the servable bundle."""
+        """Build and initialize the model (params on JAX's default
+        device), jit its prefill/decode step functions, and return the
+        servable bundle.
+
+        The init is one jitted program: run op by op, a full-width
+        model compiles each op per leaf shape and keeps every float32
+        intermediate on the device."""
         model = build_model(cfg)
-        params = model.init(jax.random.PRNGKey(seed))
+        init = jax.jit(model.init)
+        params = init(jax.random.PRNGKey(seed))
+        return cls(name, cfg, params, *jit_steps(model), model=model,
+                   init=init)
 
-        @jax.jit
-        def prefill_fn(params, tokens, cache):
-            return model.prefill(params, tokens, cache)
+    def replica(self, name: str, seed: int) -> "ModelBundle":
+        """Another bundle of this config with weights from ``seed``,
+        sharing this bundle's jitted init and steps (one compile serves
+        both)."""
+        return dataclasses.replace(
+            self, name=name, params=self.init(jax.random.PRNGKey(seed)))
 
-        @jax.jit
-        def decode_fn(params, token, cache, pos):
-            return model.decode_step(params, token, cache, pos)
 
-        bundle = cls(name, cfg, params, prefill_fn, decode_fn)
-        bundle._model = model
-        return bundle
+class ChipWeights:
+    """Per-chip copies of model params, shared by the virtual devices
+    bound to each chip.
+
+    A copy is made when a virtual device on that chip first makes the
+    model resident, and dropped when no virtual device on the chip
+    still has it resident.  A copy on the chip that already holds
+    ``bundle.params`` shares their buffers.
+    """
+
+    def __init__(self):
+        self._copies: dict[tuple[str, jax.Device], Any] = {}
+        self._holders: dict[tuple[str, jax.Device], set[int]] = {}
+
+    def acquire(self, did: int, bundle: ModelBundle,
+                device: jax.Device) -> Any:
+        """Params of ``bundle`` committed to ``device``, held for
+        virtual device ``did``."""
+        key = (bundle.name, device)
+        if key not in self._copies:
+            self._copies[key] = jax.device_put(bundle.params, device)
+        self._holders.setdefault(key, set()).add(did)
+        return self._copies[key]
+
+    def release(self, did: int, model: str, device: jax.Device) -> None:
+        """Virtual device ``did`` no longer holds ``model``; drop the
+        chip's copy if it was the last holder."""
+        key = (model, device)
+        holders = self._holders[key]
+        holders.discard(did)
+        if not holders:
+            del self._holders[key]
+            del self._copies[key]
+
+    def placed(self) -> set[tuple[str, jax.Device]]:
+        """``(model, device)`` pairs that currently have a copy."""
+        return set(self._copies)
 
 
 @dataclasses.dataclass
 class VirtualDevice:
-    """One scheduling unit: holds at most one resident model's params
-    plus saved prefix caches keyed by (group, model)."""
+    """One scheduling unit bound to a chip (``device``): holds at most
+    one resident model's params, committed to that chip, plus saved
+    prefix caches keyed by (group, model)."""
     did: int
+    device: jax.Device
+    weights: ChipWeights
     resident: Optional[str] = None
+    params: Any = None
     prefix_caches: dict = dataclasses.field(default_factory=dict)
 
     def ensure_resident(self, bundle: ModelBundle,
                         switch_sleep: float = 0.0) -> bool:
         """Returns True if a switch happened.
 
-        A residency switch drops incompatible prefix caches and — in a
-        real deployment — swaps HBM weights; the swap is emulated by
+        A residency switch drops incompatible prefix caches, releases
+        the previous model's chip copy and places ``bundle``'s params
+        on this device's chip (see :class:`ChipWeights`).  The HBM
+        weight swap of a full deployment is additionally emulated by
         ``switch_sleep`` seconds so measured τ reflects switch cost.
         The default sleep is 0 (tests must stay fast); calibration and
         measurement runs pass :func:`calibrated_switch_sleep`-derived
@@ -104,6 +172,9 @@ class VirtualDevice:
             return False
         self.prefix_caches = {k: v for k, v in self.prefix_caches.items()
                               if k[1] == bundle.name}
+        if self.resident is not None:
+            self.weights.release(self.did, self.resident, self.device)
+        self.params = self.weights.acquire(self.did, bundle, self.device)
         self.resident = bundle.name
         if switch_sleep:
             time.sleep(switch_sleep)
@@ -119,7 +190,7 @@ class StageResult:
     sid: str
     device_ids: tuple[int, ...]
     tokens_out: jax.Array           # [num_queries, gen_len]
-    wall_s: float
+    wall_s: float                   # dispatch to tokens ready
     switched: bool
     prefix_hit: bool
     # calibration features (measure -> fit -> profile loop)
@@ -129,6 +200,8 @@ class StageResult:
     output_tokens: int = 0          # per query
     switches: int = 0               # residency switches across shards
     prefix_fraction: float = 0.0    # fraction of queries with warm hit
+    # per-shard tokens in placement order, each on its device's chip
+    shards: tuple[jax.Array, ...] = ()
 
 
 class ServingEngine:
@@ -160,6 +233,12 @@ class ServingEngine:
     :meth:`run_workflow` retries them (same placement, fresh attempt
     counter) up to the plan's ``max_retries`` — the real-execution
     mirror of the scheduler's simulated retry path.
+
+    Virtual device ``i`` is bound to ``chips[i % len(chips)]``
+    (default ``jax.devices()``): on one chip every virtual device
+    shares it, on several the placements spread over them.  A shard
+    runs on its virtual device's chip, with its prompts, cache and
+    the resident params committed there.
     """
 
     def __init__(self, models: dict[str, ModelBundle], n_devices: int,
@@ -167,9 +246,14 @@ class ServingEngine:
                  switch_sleep: float = 0.0,
                  switch_time_scale: float = 0.0,
                  calibration: Optional[CalibrationProfile] = None,
-                 faults: Optional[FaultInjector] = None):
+                 faults: Optional[FaultInjector] = None,
+                 chips: Optional[Sequence[jax.Device]] = None):
         self.models = models
-        self.devices = [VirtualDevice(i) for i in range(n_devices)]
+        chips = list(chips) if chips is not None else jax.devices()
+        self.weights = ChipWeights()
+        self.devices = [VirtualDevice(i, chips[i % len(chips)],
+                                      self.weights)
+                        for i in range(n_devices)]
         self.gen_len = gen_len
         self.prompt_len = prompt_len
         self.switch_sleep = switch_sleep
@@ -243,6 +327,7 @@ class ServingEngine:
         hit_queries = 0
         outs = []
         q0 = 0
+        max_len = self.prompt_len + self.gen_len
         for did, nq in zip(placement.devices, placement.shard_sizes):
             if nq == 0:
                 continue
@@ -250,7 +335,7 @@ class ServingEngine:
             if dev.ensure_resident(bundle,
                                    self._switch_sleep_for(bundle)):
                 n_switches += 1
-            shard = prompts[q0: q0 + nq]
+            shard = jax.device_put(prompts[q0: q0 + nq], dev.device)
             q0 += nq
             cache_key = (stage.prefix_group, stage.model, nq)
             # prefix reuse is emulated at the bookkeeping level: a
@@ -263,31 +348,36 @@ class ServingEngine:
             if (stage.cache_reuse and stage.prefix_group is not None
                     and cache_key in dev.prefix_caches):
                 hit_queries += nq
-            max_len = self.prompt_len + self.gen_len
-            model = bundle._model
-            fresh = model.init_cache(nq, max_len)
-            logits, kv = bundle.prefill(bundle.params, shard, fresh)
+            fresh = jax.device_put(bundle.model.init_cache(nq, max_len),
+                                   dev.device)
+            logits, kv = bundle.prefill(dev.params, shard, fresh)
             if stage.keep_cache and stage.prefix_group is not None:
                 dev.prefix_caches[cache_key] = kv
             tok = jnp.argmax(logits[:, -1:], -1).astype(jnp.int32)
             gen = [tok]
             pos = shard.shape[1]
             for step in range(self.gen_len - 1):
-                logits, kv = bundle.decode(bundle.params, tok, kv,
+                logits, kv = bundle.decode(dev.params, tok, kv,
                                            jnp.int32(pos + step))
                 tok = jnp.argmax(logits, -1).astype(jnp.int32)
                 gen.append(tok)
             outs.append(jnp.concatenate(gen, axis=1))
-        tokens = jnp.concatenate(outs, axis=0) if outs else \
-            jnp.zeros((0, self.gen_len), jnp.int32)
+        # shards on other chips are gathered to the first shard's chip;
+        # the clock stops once every generated token is ready
+        tokens = (jnp.concatenate([jax.device_put(o, outs[0].device)
+                                   for o in outs], axis=0)
+                  if outs else jnp.zeros((0, self.gen_len), jnp.int32))
+        tokens.block_until_ready()
+        wall_s = time.perf_counter() - t0
         n_q = int(tokens.shape[0])
         res = StageResult(
             stage.sid, placement.devices, tokens,
-            time.perf_counter() - t0, n_switches > 0, hit_queries > 0,
+            wall_s, n_switches > 0, hit_queries > 0,
             model=stage.model, queries=n_q,
             prompt_tokens=self.prompt_len, output_tokens=self.gen_len,
             switches=n_switches,
-            prefix_fraction=hit_queries / n_q if n_q else 0.0)
+            prefix_fraction=hit_queries / n_q if n_q else 0.0,
+            shards=tuple(outs))
         self.log.append(res)
         return res
 
@@ -312,9 +402,10 @@ class ServingEngine:
                      and all(p in completed for p in wf.stages[sid].parents)]
             placements = policy.plan(wf, state, ready)
             if not placements:
-                sid = ready[0]
-                placements = [Placement(wf.wid, sid, (0,),
-                                        (wf.num_queries,))]
+                raise RuntimeError(
+                    f"policy {type(policy).__name__} returned no "
+                    f"placement for workflow {wf.wid!r} with ready "
+                    f"stages {ready}")
             for p in placements:
                 if p.sid in completed:
                     continue

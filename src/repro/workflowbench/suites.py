@@ -200,6 +200,32 @@ def overloaded_serving_trace(n_workflows: int = 18, rate: float = 14.0,
                                  mix="mixed")
 
 
+def agentic_workflow(wid: str, num_queries: int = 8) -> Workflow:
+    """Retrieve -> two workers -> merge over two model families, the
+    DAG the serving engine runs (``examples/serve_workflow.py``,
+    ``chip_smoke.py``).
+
+    ``retrieve``/``work_b``/``merge`` share the ``ctx`` prefix group on
+    ``qwen-7b`` and ``work_a`` runs on ``llama-8b``, so a plan prices
+    residency switches against prefix reuse; ``retrieve`` may split
+    over two devices.
+    """
+    stages = {
+        "retrieve": Stage("retrieve", "qwen-7b", base_cost={-1: 0.01},
+                          prefix_group="ctx", max_shards=2,
+                          output_tokens=128),
+        "work_a": Stage("work_a", "llama-8b", base_cost={-1: 0.02},
+                        parents=("retrieve",), output_tokens=256),
+        "work_b": Stage("work_b", "qwen-7b", base_cost={-1: 0.02},
+                        prefix_group="ctx", parents=("retrieve",),
+                        output_tokens=256),
+        "merge": Stage("merge", "qwen-7b", base_cost={-1: 0.015},
+                       prefix_group="ctx",
+                       parents=("work_a", "work_b")),
+    }
+    return Workflow(wid=wid, stages=stages, num_queries=num_queries)
+
+
 def routed_workflow_instance(index: int, num_queries: int = 8,
                              candidates: tuple = (("qwen-7b", 0.92),
                                                   ("llama-3b", 0.84))

@@ -122,3 +122,124 @@ def test_serving_deterministic_outputs(bundles):
         outs.append({k: v.tokens_out for k, v in res.items()})
     for k in outs[0]:
         assert bool(jnp.all(outs[0][k] == outs[1][k]))
+
+
+class _Recording:
+    """Wraps a policy and keeps every placement it returns."""
+
+    def __init__(self, inner):
+        self.inner = inner
+        self.placements = []
+
+    def plan(self, wf, state, ready):
+        out = self.inner.plan(wf, state, ready)
+        self.placements.extend(out)
+        return out
+
+
+def _plain_greedy(bundle, prompts, gen_len, max_len):
+    """Greedy decode on JAX's default device with nothing committed:
+    the engine's computation before shards were bound to chips."""
+    logits, kv = bundle.prefill(bundle.params, prompts,
+                                bundle.model.init_cache(prompts.shape[0],
+                                                        max_len))
+    tok = jnp.argmax(logits[:, -1:], -1).astype(jnp.int32)
+    gen = [tok]
+    for step in range(gen_len - 1):
+        logits, kv = bundle.decode(bundle.params, tok, kv,
+                                   jnp.int32(prompts.shape[1] + step))
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        gen.append(tok)
+    return jnp.concatenate(gen, axis=1)
+
+
+def test_shards_committed_to_their_virtual_devices_chip(bundles):
+    wf = _workflow()
+    engine = ServingEngine(bundles, n_devices=2, gen_len=3, prompt_len=8)
+    state = fresh_state(homogeneous_cluster(2))
+    prompts = jax.random.randint(jax.random.PRNGKey(5), (4, 8), 0, 256)
+    policy = _Recording(make_policy("FATE"))
+    results = engine.run_workflow(wf, policy, state, prompts)
+    for p in policy.placements:
+        r = results[p.sid]
+        used = [d for d, n in zip(p.devices, p.shard_sizes) if n]
+        assert len(r.shards) == len(used)
+        for did, shard in zip(used, r.shards):
+            chip = engine.devices[did].device
+            assert shard.committed
+            assert shard.sharding.device_set == {chip}
+        for dev in engine.devices:
+            if dev.params is not None:
+                leaves = jax.tree.leaves(dev.params)
+                assert all(x.sharding.device_set == {dev.device}
+                           for x in leaves)
+        assert bool(jnp.all(r.tokens_out
+                            == jnp.concatenate(r.shards, axis=0)))
+
+
+def test_bound_engine_tokens_equal_plain_default_device_run(bundles):
+    wf = _workflow()
+    gen_len, prompt_len = 3, 8
+    engine = ServingEngine(bundles, n_devices=2, gen_len=gen_len,
+                           prompt_len=prompt_len)
+    assert {d.device for d in engine.devices} == {jax.devices()[0]}
+    state = fresh_state(homogeneous_cluster(2))
+    prompts = jax.random.randint(jax.random.PRNGKey(6), (4, prompt_len),
+                                 0, 256)
+    policy = _Recording(make_policy("FATE"))
+    results = engine.run_workflow(wf, policy, state, prompts)
+    for p in policy.placements:
+        bundle = bundles[wf.stages[p.sid].model]
+        parts, q0 = [], 0
+        for n in p.shard_sizes:
+            if n:
+                parts.append(_plain_greedy(bundle, prompts[q0:q0 + n],
+                                           gen_len, prompt_len + gen_len))
+                q0 += n
+        want = jnp.concatenate(parts, axis=0)
+        assert bool(jnp.all(results[p.sid].tokens_out == want))
+
+
+def test_run_stage_returns_ready_tokens(bundles):
+    from repro.core.planner import Placement
+
+    wf = _workflow()
+    engine = ServingEngine(bundles, n_devices=2, gen_len=4, prompt_len=8)
+    prompts = jax.random.randint(jax.random.PRNGKey(7), (4, 8), 0, 256)
+    res = engine.run_stage(wf, wf.stages["retrieve"],
+                           Placement(wf.wid, "retrieve", (0, 1), (2, 2)),
+                           prompts)
+    assert res.tokens_out.is_ready()
+    assert all(s.is_ready() for s in res.shards)
+    assert res.wall_s > 0.0
+
+
+def test_run_workflow_raises_on_empty_plan(bundles):
+    class NoPlan:
+        def plan(self, wf, state, ready):
+            return []
+
+    wf = _workflow()
+    engine = ServingEngine(bundles, n_devices=2, gen_len=2, prompt_len=8)
+    state = fresh_state(homogeneous_cluster(2))
+    prompts = jax.random.randint(jax.random.PRNGKey(8), (4, 8), 0, 256)
+    with pytest.raises(RuntimeError, match="no placement"):
+        engine.run_workflow(wf, NoPlan(), state, prompts)
+    assert engine.log == []
+
+
+def test_chip_copy_dropped_after_last_holder_switches(bundles):
+    chip = jax.devices()[0]
+    engine = ServingEngine(bundles, n_devices=2, chips=[chip])
+    a, b = bundles["qwen-7b"], bundles["llama-8b"]
+    d0, d1 = engine.devices
+    assert d0.ensure_resident(a) and d1.ensure_resident(a)
+    assert engine.weights.placed() == {("qwen-7b", chip)}
+    assert not d0.ensure_resident(a)           # already resident
+    assert d0.ensure_resident(b)
+    # d1 still holds qwen-7b on this chip: its copy stays
+    assert engine.weights.placed() == {("qwen-7b", chip),
+                                       ("llama-8b", chip)}
+    assert d1.ensure_resident(b)
+    assert engine.weights.placed() == {("llama-8b", chip)}
+    assert d0.params is d1.params

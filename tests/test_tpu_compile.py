@@ -1,0 +1,124 @@
+"""Ahead-of-time compiles for a described TPU v5e, without a chip.
+
+The TPU compiler refuses what interpret mode and the CPU backend accept
+(tiling rules, primitives with no Mosaic lowering, programs that do not
+fit HBM), so the served step functions at full qwen3-1.7b width and the
+Pallas kernels at real widths are compiled here for one v5e chip.
+Nothing runs: these tests say nothing about results or times.
+
+The topology is described inside a module fixture, never at import:
+only one process may load the TPU library at a time, and every test
+worker imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.archs import ARCHS
+from repro.kernels import ops
+from repro.models.families import build_model
+from repro.serving.engine import jit_steps
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no TPU here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    """Shapes of ``tree`` placed on ``sharding`` (no arrays exist)."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _spec(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_qwen3_full_width_prefill_and_decode_compile(one_chip):
+    """The engine's jitted steps for the full qwen3-1.7b config, at the
+    shard shape the chip smoke serves (8 queries, 128-token prompts,
+    8 generated tokens)."""
+    cfg = ARCHS["qwen3-1.7b"]
+    model = build_model(cfg)
+    prefill, decode = jit_steps(model)
+    batch, prompt_len, gen_len = 8, 128, 8
+    params = _on(one_chip, jax.eval_shape(model.init,
+                                          jax.random.PRNGKey(0)))
+    cache = _on(one_chip, model.init_cache(batch, prompt_len + gen_len,
+                                           abstract=True))
+    tokens = _spec(one_chip, (batch, prompt_len), jnp.int32)
+    compiled = prefill.lower(params, tokens, cache).compile()
+    mem = compiled.memory_analysis()
+    # 1.72e9 bf16 params fit one 16 GB chip with room for two bundles
+    assert mem.argument_size_in_bytes < 4.0e9
+    token = _spec(one_chip, (batch, 1), jnp.int32)
+    pos = _spec(one_chip, (), jnp.int32)
+    decode.lower(params, token, cache, pos).compile()
+
+
+@pytest.mark.parametrize("sq", [2048, 40])          # full and ragged
+def test_flash_attention_compiles(one_chip, sq):
+    h, kv, d = 16, 8, 128                            # qwen3-1.7b heads
+    q = _spec(one_chip, (1, sq, h, d))
+    k = _spec(one_chip, (1, sq, kv, d))
+    ops.flash_attention.lower(q, k, k, interpret=False).compile()
+
+
+def test_decode_attention_compiles(one_chip):
+    b, s, h, kv, d = 8, 2048, 16, 8, 128             # qwen3-1.7b heads
+    q = _spec(one_chip, (b, 1, h, d))
+    kc = _spec(one_chip, (b, s, kv, d))
+    n = _spec(one_chip, (), jnp.int32)
+    ops.decode_attention.lower(q, kc, kc, n, interpret=False).compile()
+
+
+def test_moe_gemm_compiles(one_chip):
+    moe = ARCHS["granite-moe-3b-a800m"].moe
+    d = ARCHS["granite-moe-3b-a800m"].d_model
+    e, c = moe.num_experts, 256
+    x = _spec(one_chip, (e, c, d))
+    w = _spec(one_chip, (e, d, moe.d_expert))
+    ops.moe_gemm.lower(x, w, interpret=False).compile()
+
+
+def test_rwkv6_scan_compiles(one_chip):
+    cfg = ARCHS["rwkv6-3b"]
+    b, s, d = 1, 256, cfg.rwkv.head_dim
+    h = cfg.d_model // d
+    x = _spec(one_chip, (b, s, h, d), jnp.float32)
+    bonus = _spec(one_chip, (h, d), jnp.float32)
+    ops.rwkv6_scan.lower(x, x, x, x, bonus, chunk=cfg.rwkv.chunk,
+                         interpret=False).compile()
+
+
+def test_mamba2_scan_compiles(one_chip):
+    cfg = ARCHS["zamba2-2.7b"]
+    ssm = cfg.ssm
+    b, s, p, n = 1, 512, ssm.head_dim, ssm.state_dim
+    h = ssm.expand * cfg.d_model // p
+    xh = _spec(one_chip, (b, s, h, p), jnp.float32)
+    bc = _spec(one_chip, (b, s, n), jnp.float32)
+    dt = _spec(one_chip, (b, s, h), jnp.float32)
+    a_log = _spec(one_chip, (h,), jnp.float32)
+    ops.mamba2_scan.lower(xh, bc, bc, dt, a_log, chunk=ssm.chunk,
+                          interpret=False).compile()
