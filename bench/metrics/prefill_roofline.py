@@ -1,0 +1,8 @@
+"""The prefill steps' share of their roofline: the least time the chip
+could take for their operations or their bytes, over the device time they
+took."""
+import calls
+
+
+def read(view):
+    return calls.roofline(view, "prefill")
