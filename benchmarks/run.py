@@ -6,7 +6,6 @@ and writes per-experiment CSVs under results/workflow.
     PYTHONPATH=src python -m benchmarks.run              # everything
     PYTHONPATH=src python -m benchmarks.run --only table1,table12
     PYTHONPATH=src python -m benchmarks.run --quick      # small slices
-    PYTHONPATH=src python -m benchmarks.run --interpret  # kernels on CPU
 
 Exits nonzero when any phase raised.
 """
@@ -24,13 +23,10 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     ap.add_argument("--quick", action="store_true")
-    ap.add_argument("--interpret", action="store_true",
-                    help="run the Pallas kernels in the interpreter "
-                         "(needed on the CPU backend)")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
-    from benchmarks import kernels_bench, tables
+    from benchmarks import tables
 
     benches = {
         "table1": lambda: tables.table1_main(full=not args.quick),
@@ -42,7 +38,6 @@ def main() -> None:
         "table11": tables.table11_perturbation,
         "table12": tables.table12_solver,
         "fig2": tables.fig2_ecdf,
-        "kernels": lambda: kernels_bench.run(interpret=args.interpret),
         "roofline": _roofline_summary,
     }
     all_rows: list[str] = []

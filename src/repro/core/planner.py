@@ -26,7 +26,6 @@ inside a single exact solve.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Mapping, Optional, Sequence
 
 import numpy as np
@@ -40,6 +39,7 @@ from repro.core.routing import RoutingConfig, StageRouter, variant_stage
 from repro.core.scoring import FrontierScores, ScoreParams, Scorer
 from repro.core.state import ExecutionState
 from repro.core.workflow import Stage, StageKey, Workflow
+from repro.spans import span
 
 
 @dataclasses.dataclass
@@ -145,7 +145,8 @@ class FrontierPlanner:
         # (serving without retirement calls) evict oldest-first.
         self._wave_scores: dict[str, FrontierScores] = {}
         self._max_cached_workflows = 64
-        # per-phase timing accumulators (benchmarks --profile)
+        # per-phase host ms, the accumulated ``fate.plan.score`` and
+        # ``fate.plan.solve`` spans (benchmarks --profile)
         self.phase_ms = {"full_build": 0.0, "delta_rescore": 0.0,
                          "solve": 0.0}
 
@@ -328,19 +329,18 @@ class FrontierPlanner:
         for wid, sids in by_wid.items():
             wf = workflows[wid]
             scorer.set_frontier_shared(wf, sids, counts, pressure)
-            t0 = time.perf_counter()
-            entry = session.get(wid)
-            if entry is None:             # first wave for this workflow
-                prev, n_scored = self._wave_scores.get(wid), 0
-            else:
-                prev, n_scored = entry
-            if not self.use_delta:
-                prev = None
-            fs = scorer.rescore_matrix(wf, sids, prev,
-                                       consume=(n_scored != 1),
-                                       dirty=dirty)
-            key = "full_build" if fs.built_full else "delta_rescore"
-            self.phase_ms[key] += (time.perf_counter() - t0) * 1e3
+            with span("fate.plan.score", self.phase_ms) as sp:
+                entry = session.get(wid)
+                if entry is None:         # first wave for this workflow
+                    prev, n_scored = self._wave_scores.get(wid), 0
+                else:
+                    prev, n_scored = entry
+                if not self.use_delta:
+                    prev = None
+                fs = scorer.rescore_matrix(wf, sids, prev,
+                                           consume=(n_scored != 1),
+                                           dirty=dirty)
+                sp.key = "full_build" if fs.built_full else "delta_rescore"
             if n_scored == 0:
                 self._store_snapshot(wid, fs)  # cross-session snapshot
             session[wid] = (fs, n_scored + 1)
@@ -389,9 +389,8 @@ class FrontierPlanner:
         if not problems:
             return []
         problem = merge_problems(problems)
-        t0 = time.perf_counter()
-        sol = solve_frontier_exact(problem, self.time_limit)
-        self.phase_ms["solve"] += (time.perf_counter() - t0) * 1e3
+        with span("fate.plan.solve", self.phase_ms, "solve"):
+            sol = solve_frontier_exact(problem, self.time_limit)
         if self.warm_start:
             # next wave's (and next replan's) warm start; revoked
             # commitments reappear as rows and pick their old device
@@ -588,9 +587,8 @@ class FrontierPlanner:
             if not probs:
                 continue
             problem = merge_problems(probs)
-            t0 = time.perf_counter()
-            sol = solve_frontier_exact(problem, self.time_limit)
-            self.phase_ms["solve"] += (time.perf_counter() - t0) * 1e3
+            with span("fate.plan.solve", self.phase_ms, "solve"):
+                sol = solve_frontier_exact(problem, self.time_limit)
             self.solve_log.append(SolveRecord(
                 wall_time=sol.wall_time, nodes=sol.nodes,
                 status=sol.status, n_rows=len(problem.rows),
@@ -754,11 +752,10 @@ class FrontierPlanner:
         if not ready:
             return [], None
         scorer.set_frontier(wf, ready)
-        t0 = time.perf_counter()
-        fs = scorer.rescore_matrix(wf, ready, prev, consume=consume,
-                                   dirty=dirty)
-        key = "full_build" if fs.built_full else "delta_rescore"
-        self.phase_ms[key] += (time.perf_counter() - t0) * 1e3
+        with span("fate.plan.score", self.phase_ms) as sp:
+            fs = scorer.rescore_matrix(wf, ready, prev, consume=consume,
+                                       dirty=dirty)
+            sp.key = "full_build" if fs.built_full else "delta_rescore"
         devices = fs.devices
 
         # margin: same all-pairs mean as the scalar path, accumulated
@@ -782,9 +779,8 @@ class FrontierPlanner:
 
         problem = FrontierProblem(rows, devices, np.array(weights),
                                   exclusive=exclusive)
-        t0 = time.perf_counter()
-        sol = solve_frontier_exact(problem, self.time_limit)
-        self.phase_ms["solve"] += (time.perf_counter() - t0) * 1e3
+        with span("fate.plan.solve", self.phase_ms, "solve"):
+            sol = solve_frontier_exact(problem, self.time_limit)
         self.solve_log.append(SolveRecord(
             wall_time=sol.wall_time, nodes=sol.nodes, status=sol.status,
             n_rows=len(rows), n_devices=len(devices),

@@ -27,6 +27,7 @@ from repro.core.state import ExecutionState
 from repro.core.workflow import (DEFAULT_PROFILES, ModelProfile, Stage,
                                  Workflow)
 from repro.models.families import build_model
+from repro.spans import span
 
 
 def calibrated_switch_sleep(profile: ModelProfile,
@@ -321,6 +322,11 @@ class ServingEngine:
                     f"injected fault: stage {wf.wid}/{stage.sid} on "
                     f"devices {placement.devices} failed at "
                     f"{frac:.0%} of its run (attempt {attempt})")
+        with span("fate.stage", wid=wf.wid, sid=stage.sid):
+            return self._run_stage(stage, placement, prompts)
+
+    def _run_stage(self, stage: Stage, placement: Placement,
+                   prompts: jax.Array) -> StageResult:
         bundle = self.models[stage.model]
         t0 = time.perf_counter()
         n_switches = 0
@@ -332,42 +338,46 @@ class ServingEngine:
             if nq == 0:
                 continue
             dev = self.devices[did]
-            if dev.ensure_resident(bundle,
-                                   self._switch_sleep_for(bundle)):
-                n_switches += 1
-            shard = jax.device_put(prompts[q0: q0 + nq], dev.device)
-            q0 += nq
-            cache_key = (stage.prefix_group, stage.model, nq)
-            # prefix reuse is emulated at the bookkeeping level: a
-            # saved cache marks the hit (κ state the scheduler scored
-            # for), but prefill below always starts fresh — replaying
-            # the saved KV would need per-query prefix alignment the
-            # tiny-model substrate doesn't model.  (The seed fetched
-            # the cache object here and never used it; that dead read
-            # is removed.)
-            if (stage.cache_reuse and stage.prefix_group is not None
-                    and cache_key in dev.prefix_caches):
-                hit_queries += nq
-            fresh = jax.device_put(bundle.model.init_cache(nq, max_len),
-                                   dev.device)
-            logits, kv = bundle.prefill(dev.params, shard, fresh)
-            if stage.keep_cache and stage.prefix_group is not None:
-                dev.prefix_caches[cache_key] = kv
-            tok = jnp.argmax(logits[:, -1:], -1).astype(jnp.int32)
+            with span("fate.stage.switch"):
+                if dev.ensure_resident(bundle,
+                                       self._switch_sleep_for(bundle)):
+                    n_switches += 1
+            with span("fate.stage.put"):
+                shard = jax.device_put(prompts[q0: q0 + nq], dev.device)
+                q0 += nq
+                cache_key = (stage.prefix_group, stage.model, nq)
+                # prefix reuse is emulated at the bookkeeping level: a
+                # saved cache marks the hit (κ state the scheduler
+                # scored for), but prefill below always starts fresh —
+                # replaying the saved KV would need per-query prefix
+                # alignment the tiny-model substrate doesn't model.
+                if (stage.cache_reuse and stage.prefix_group is not None
+                        and cache_key in dev.prefix_caches):
+                    hit_queries += nq
+                fresh = jax.device_put(bundle.model.init_cache(nq, max_len),
+                                       dev.device)
+            with span("fate.stage.prefill"):
+                logits, kv = bundle.prefill(dev.params, shard, fresh)
+                if stage.keep_cache and stage.prefix_group is not None:
+                    dev.prefix_caches[cache_key] = kv
+                tok = jnp.argmax(logits[:, -1:], -1).astype(jnp.int32)
             gen = [tok]
             pos = shard.shape[1]
-            for step in range(self.gen_len - 1):
-                logits, kv = bundle.decode(dev.params, tok, kv,
-                                           jnp.int32(pos + step))
-                tok = jnp.argmax(logits, -1).astype(jnp.int32)
-                gen.append(tok)
+            with span("fate.stage.decode"):
+                for step in range(self.gen_len - 1):
+                    logits, kv = bundle.decode(dev.params, tok, kv,
+                                               jnp.int32(pos + step))
+                    tok = jnp.argmax(logits, -1).astype(jnp.int32)
+                    gen.append(tok)
             outs.append(jnp.concatenate(gen, axis=1))
         # shards on other chips are gathered to the first shard's chip;
         # the clock stops once every generated token is ready
-        tokens = (jnp.concatenate([jax.device_put(o, outs[0].device)
-                                   for o in outs], axis=0)
-                  if outs else jnp.zeros((0, self.gen_len), jnp.int32))
-        tokens.block_until_ready()
+        with span("fate.stage.gather"):
+            tokens = (jnp.concatenate([jax.device_put(o, outs[0].device)
+                                       for o in outs], axis=0)
+                      if outs else jnp.zeros((0, self.gen_len), jnp.int32))
+        with span("fate.stage.ready"):
+            tokens.block_until_ready()
         wall_s = time.perf_counter() - t0
         n_q = int(tokens.shape[0])
         res = StageResult(
@@ -391,6 +401,11 @@ class ServingEngine:
         emulates — asserted here, at profile-load time, so engine and
         planner can never silently diverge.
         """
+        with span("fate.workflow", wid=wf.wid):
+            return self._run_workflow(wf, policy, state, prompts)
+
+    def _run_workflow(self, wf: Workflow, policy, state: ExecutionState,
+                      prompts: jax.Array) -> dict[str, StageResult]:
         if self.calibration is not None:
             self.calibration.assert_consistent(state.profiles)
         results: dict[str, StageResult] = {}
@@ -400,7 +415,8 @@ class ServingEngine:
             ready = [sid for sid in wf.topo_order
                      if sid not in completed
                      and all(p in completed for p in wf.stages[sid].parents)]
-            placements = policy.plan(wf, state, ready)
+            with span("fate.plan"):
+                placements = policy.plan(wf, state, ready)
             if not placements:
                 raise RuntimeError(
                     f"policy {type(policy).__name__} returned no "
@@ -421,16 +437,18 @@ class ServingEngine:
                         if attempt >= max_retries:
                             raise
                         self.n_fault_retries += 1
-                results[p.sid] = res
-                completed.add(p.sid)
-                now = time.perf_counter() - t_start
-                state.now = now
-                for d in p.devices:
-                    state.set_free_at(d, now)
-                    state.set_resident(d, stage.model)
-                    if stage.keep_cache:
-                        state.warm_prefix(d, stage.prefix_group,
-                                          stage.model, wf.num_queries, now)
-                state.output_loc[(wf.wid, p.sid)] = p.devices
-                state.completed.add((wf.wid, p.sid))
+                with span("fate.state"):
+                    results[p.sid] = res
+                    completed.add(p.sid)
+                    now = time.perf_counter() - t_start
+                    state.now = now
+                    for d in p.devices:
+                        state.set_free_at(d, now)
+                        state.set_resident(d, stage.model)
+                        if stage.keep_cache:
+                            state.warm_prefix(d, stage.prefix_group,
+                                              stage.model, wf.num_queries,
+                                              now)
+                    state.output_loc[(wf.wid, p.sid)] = p.devices
+                    state.completed.add((wf.wid, p.sid))
         return results

@@ -51,6 +51,7 @@ DOCSTRING_MODULES = [
     "src/repro/core/routing.py",
     "src/repro/serving/engine.py",
     "src/repro/serving/gateway.py",
+    "src/repro/spans.py",
 ]
 
 MARKDOWN_FILES = ["README.md", *sorted(
