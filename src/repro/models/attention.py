@@ -164,6 +164,53 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     return out.reshape(b, 1, h, d).astype(q.dtype)
 
 
+def _softmax_parts(scores: list, weigh) -> jax.Array:
+    """One softmax over the last axis of the score ``parts`` (f32, masked
+    entries at NEG_INF), as if they were one array; returns the sum over
+    parts of ``weigh(i, w)``, with ``w`` part i's normalized weights."""
+    m = scores[0].max(axis=-1)
+    for sc in scores[1:]:
+        m = jnp.maximum(m, sc.max(axis=-1))
+    e = [jnp.exp(sc - m[..., None]) for sc in scores]
+    denom = sum(x.sum(axis=-1) for x in e)
+    return sum(weigh(i, x / denom[..., None]) for i, x in enumerate(e))
+
+
+def decode_attention_parts(q: jax.Array, parts: list) -> jax.Array:
+    """Single-token attention over a cache held in ``parts``, each
+    ``(k, v, valid)``: k/v [B, n, KV, D], valid [n] bool, as one softmax
+    (f32 scores and accumulation, as :func:`decode_attention`). The
+    token's own k/v is a part of one always-valid row, so no cache has
+    to be written before it is read."""
+    b, _, h, d = q.shape
+    kv = parts[0][0].shape[2]
+    qr = q.reshape(b, kv, h // kv, d)
+    scores = [jnp.where(valid[None, None, None],
+                        jnp.einsum("bkgd,bskd->bkgs", qr, k,
+                                   preferred_element_type=jnp.float32)
+                        * d ** -0.5, NEG_INF)
+              for k, _, valid in parts]
+    out = _softmax_parts(scores, lambda i, w: jnp.einsum(
+        "bkgs,bskd->bkgd", w.astype(parts[i][1].dtype), parts[i][1],
+        preferred_element_type=jnp.float32))
+    return out.reshape(b, 1, h, d).astype(q.dtype)
+
+
+def put_rows(stack: jax.Array, rows: jax.Array, layer: jax.Array | int,
+             pos: jax.Array | int) -> jax.Array:
+    """``stack`` [L, B, S, ...] with ``rows`` [B, n, ...] written into
+    layer ``layer`` at positions ``pos .. pos + n - 1``: an in-place
+    update of those rows where the stack is a loop carry."""
+    start = (layer, 0, pos) + (0,) * (stack.ndim - 3)
+    return jax.lax.dynamic_update_slice(
+        stack, rows[None].astype(stack.dtype), tuple(map(_as_idx, start)))
+
+
+def layer_of(stack: jax.Array, layer: jax.Array | int) -> jax.Array:
+    """Layer ``layer`` of ``stack`` [L, ...]."""
+    return jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+
+
 # ---------------------------------------------------------------------------
 # GQA attention block
 # ---------------------------------------------------------------------------
@@ -224,37 +271,22 @@ def gqa_attend(p: dict, cfg, x: jax.Array, positions: jax.Array, *,
                cache_len: jax.Array | int = 0):
     """Full-sequence (train/prefill) or decode attention.
 
-    Returns (out, new_cache).  cache = (k_cache, v_cache) of static shape
-    [B, S_max, KV, D]; prefill writes positions [0, Sq); decode appends
-    at ``cache_len``.
+    Returns (out, new_cache).  cache = (k_cache, v_cache) of one layer,
+    static shape [B, S_max, KV, D]; prefill writes positions [0, Sq);
+    decode appends at ``cache_len``. (The stacked caches of ``DecoderLM``
+    and their sliding windows go through :func:`gqa_attend_stacked`.)
     """
     b, sq, _ = x.shape
     q, k, v = gqa_project_qkv(p, cfg, x, positions)
     new_cache = None
     if cache is not None:
         k_cache, v_cache = cache
-        s_cache = k_cache.shape[1]
-        if sq == 1 and window and s_cache == window:
-            # rolling window cache: shift left, append at the end; valid
-            # entries are the last min(pos+1, W) slots.
-            k_cache = jnp.concatenate(
-                [k_cache[:, 1:], k.astype(k_cache.dtype)], axis=1)
-            v_cache = jnp.concatenate(
-                [v_cache[:, 1:], v.astype(v_cache.dtype)], axis=1)
-            eff = jnp.minimum(_as_idx(cache_len) + 1, window)
-            out = _windowed_decode(q, k_cache, v_cache, eff)
-            return _proj_out(p, out), (k_cache, v_cache)
-        if sq > 1 and s_cache < sq:
-            # prefill longer than the (windowed) cache: keep the tail
-            k_cache = k[:, -s_cache:].astype(k_cache.dtype)
-            v_cache = v[:, -s_cache:].astype(v_cache.dtype)
-        else:
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, k.astype(k_cache.dtype),
-                (0, _as_idx(cache_len), 0, 0))
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, v.astype(v_cache.dtype),
-                (0, _as_idx(cache_len), 0, 0))
+        k_cache = jax.lax.dynamic_update_slice(
+            k_cache, k.astype(k_cache.dtype),
+            (0, _as_idx(cache_len), 0, 0))
+        v_cache = jax.lax.dynamic_update_slice(
+            v_cache, v.astype(v_cache.dtype),
+            (0, _as_idx(cache_len), 0, 0))
         new_cache = (k_cache, v_cache)
         if sq == 1:   # decode against a full-length cache
             out = decode_attention(q, k_cache, v_cache,
@@ -264,6 +296,72 @@ def gqa_attend(p: dict, cfg, x: jax.Array, positions: jax.Array, *,
     out = flash_attention_sp(q, k, v, causal=causal, window=window,
                              n_sp=seq_parallel_degree(cfg.num_heads))
     return _proj_out(p, out), new_cache
+
+
+def gqa_attend_stacked(p: dict, cfg, x: jax.Array, positions: jax.Array, *,
+                       cache: tuple, layer: jax.Array | int,
+                       window: int = 0, cache_len: jax.Array | int = 0):
+    """Prefill or decode attention of layer ``layer`` of a stack whose
+    cache = (base, tail) is stacked over its layers: base = (k, v), each
+    [L, B, S, KV, D]; tail = (k, v), each [L, B, T, KV, D], holding
+    positions S - T .. S - 1 (base then holds only the positions before
+    them), or None.
+
+    Returns (out, (base, tail)) with only this call's rows written:
+    prefill's positions [0, Sq) into base (the last S where Sq is
+    longer); decode's one row at ``cache_len`` into the tail, or into
+    base where there is none. Decode leaves base as it was given where
+    it writes the tail. It attends over the cache as it stood plus its
+    own k/v (:func:`decode_attention_parts`). A rolling window base
+    (S == ``window``, no tail) shifts and rewrites its whole layer.
+    """
+    sq = x.shape[1]
+    q, k, v = gqa_project_qkv(p, cfg, x, positions)
+    (k_base, v_base), tail = cache
+    k_new, v_new = k.astype(k_base.dtype), v.astype(v_base.dtype)
+    s_cache = k_base.shape[2]
+    if sq == 1 and window and s_cache == window:
+        # rolling window cache: shift left, append at the end; valid
+        # entries are the last min(pos+1, W) slots.
+        k_l = jnp.concatenate([layer_of(k_base, layer)[:, 1:], k_new],
+                              axis=1)
+        v_l = jnp.concatenate([layer_of(v_base, layer)[:, 1:], v_new],
+                              axis=1)
+        eff = jnp.minimum(_as_idx(cache_len) + 1, window)
+        out = _windowed_decode(q, k_l, v_l, eff)
+        return _proj_out(p, out), ((put_rows(k_base, k_l, layer, 0),
+                                    put_rows(v_base, v_l, layer, 0)), None)
+    if sq == 1:
+        start = s_cache - (0 if tail is None else tail[0].shape[2])
+
+        def live(pos):
+            ok = pos < cache_len
+            return ok & (pos > cache_len - window) if window else ok
+
+        at = jnp.arange(s_cache)
+        parts = [(layer_of(k_base, layer), layer_of(v_base, layer),
+                  live(at) & (at < start))]
+        if tail is not None:
+            parts.append((layer_of(tail[0], layer), layer_of(tail[1], layer),
+                          live(start + jnp.arange(s_cache - start))))
+        parts.append((k_new, v_new, jnp.ones(1, bool)))
+        out = _proj_out(p, decode_attention_parts(q, parts))
+        if tail is None:
+            return out, ((put_rows(k_base, k_new, layer, cache_len),
+                          put_rows(v_base, v_new, layer, cache_len)), None)
+        return out, ((k_base, v_base), (
+            put_rows(tail[0], k_new, layer, cache_len - start),
+            put_rows(tail[1], v_new, layer, cache_len - start)))
+    at = cache_len
+    if s_cache < sq:
+        # prefill longer than the (windowed) cache: keep its last rows
+        k_new, v_new, at = k_new[:, -s_cache:], v_new[:, -s_cache:], 0
+    base = (put_rows(k_base, k_new, layer, at),
+            put_rows(v_base, v_new, layer, at))
+    # prefill attends over freshly computed k/v (cache == prefix here)
+    out = flash_attention_sp(q, k, v, causal=True, window=window,
+                             n_sp=seq_parallel_degree(cfg.num_heads))
+    return _proj_out(p, out), (base, tail)
 
 
 def _windowed_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
@@ -340,13 +438,20 @@ def _mla_queries(p: dict, cfg, x: jax.Array, positions: jax.Array):
 
 
 def mla_attend(p: dict, cfg, x: jax.Array, positions: jax.Array, *,
-               cache: Optional[jax.Array] = None,
+               cache: Optional[tuple] = None,
+               layer: jax.Array | int = 0,
                cache_len: jax.Array | int = 0):
-    """MLA with compressed-KV cache [B, S, kv_lora + rope_dim].
+    """MLA with a compressed-KV cache = (base, tail) stacked over the
+    layers of a stack, this being layer ``layer``: base [L, B, S,
+    kv_lora + rope_dim], tail [L, B, T, ...] holding positions S - T ..
+    S - 1, or None; rows are written as :func:`gqa_attend_stacked`
+    writes them.
 
     Decode uses the absorbed-matmul formulation: queries are projected
     into the latent space, so per-step work is O(S * kv_lora) and the
-    cache stays compressed (the paper-exact memory saving of MLA).
+    cache stays compressed (the paper-exact memory saving of MLA). It
+    reads the cache as it stood, with the token's own latent as one more
+    score column of the softmax.
     """
     from repro.models.layers import rms_norm
     m = cfg.mla
@@ -360,26 +465,42 @@ def mla_attend(p: dict, cfg, x: jax.Array, positions: jax.Array, *,
 
     new_cache = None
     if cache is not None:
-        packed = jnp.concatenate([c, k_rope], axis=-1).astype(cache.dtype)
-        cache = jax.lax.dynamic_update_slice(
-            cache, packed, (0, _as_idx(cache_len), 0))
-        new_cache = cache
-        c_all = cache[..., : m.kv_lora_rank]
-        kr_all = cache[..., m.kv_lora_rank:]
-        if sq == 1:   # absorbed decode
+        base, tail = cache
+        packed = jnp.concatenate([c, k_rope], axis=-1).astype(base.dtype)
+        c, k_rope = (packed[..., : m.kv_lora_rank],
+                     packed[..., m.kv_lora_rank:])
+        if sq > 1:
+            new_cache = (put_rows(base, packed, layer, cache_len), tail)
+        else:   # absorbed decode
+            s_cache = base.shape[2]
+            start = s_cache - (0 if tail is None else tail.shape[2])
+            at = jnp.arange(s_cache)
+            parts = [(layer_of(base, layer),
+                      (at < cache_len) & (at < start))]
+            if tail is not None:
+                parts.append((layer_of(tail, layer),
+                              start + jnp.arange(s_cache - start)
+                              < cache_len))
+            parts.append((packed, jnp.ones(1, bool)))
             qa = jnp.einsum("bshe,rhe->bshr", q_nope, p["w_uk"])  # latent q
-            s_lat = jnp.einsum("bshr,btr->bhst", qa, c_all)
-            s_rope = jnp.einsum("bshe,bte->bhst", q_rope, kr_all)
             scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
-            scores = (s_lat + s_rope).astype(jnp.float32) * scale
-            t = c_all.shape[1]
-            valid = jnp.arange(t) < cache_len + 1
-            scores = jnp.where(valid[None, None, None], scores, NEG_INF)
-            pr = jax.nn.softmax(scores, axis=-1)
-            lat = jnp.einsum("bhst,btr->bshr", pr.astype(c_all.dtype), c_all)
+            r = m.kv_lora_rank
+            scores = [jnp.where(
+                valid[None, None, None],
+                (jnp.einsum("bshr,btr->bhst", qa, part[..., :r])
+                 + jnp.einsum("bshe,bte->bhst", q_rope, part[..., r:])
+                 ).astype(jnp.float32) * scale, NEG_INF)
+                for part, valid in parts]
+            lat = _softmax_parts(scores, lambda i, w: jnp.einsum(
+                "bhst,btr->bshr", w.astype(base.dtype), parts[i][0][..., :r]
+            ).astype(jnp.float32)).astype(base.dtype)
             out = jnp.einsum("bshr,rhe->bshe", lat, p["w_uv"])
+            if tail is None:
+                new_cache = (put_rows(base, packed, layer, cache_len), None)
+            else:
+                new_cache = (base, put_rows(tail, packed, layer,
+                                            cache_len - start))
             return jnp.einsum("bshe,hed->bsd", out, p["wo"]), new_cache
-        c, k_rope = c_all[:, : sq], kr_all[:, : sq]
 
     # train / prefill: expand k, v per position (flash path)
     k_nope = jnp.einsum("bsr,rhe->bshe", c, p["w_uk"])

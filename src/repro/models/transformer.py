@@ -135,15 +135,21 @@ class DecoderLM:
 
     # --- forward -----------------------------------------------------------
     def _block(self, p: dict, cfg, x, positions, *, window: int,
-               cache=None, cache_len=0):
+               cache=None, layer=0, cache_len=0):
+        """One layer; ``cache`` is the whole stack's, this is its layer
+        ``layer``."""
         h = rms_norm(x, p["ln_attn"], cfg.norm_eps)
         if cfg.attention == "mla":
             a, new_cache = attn.mla_attend(p["attn"], cfg, h, positions,
-                                           cache=cache, cache_len=cache_len)
-        else:
-            a, new_cache = attn.gqa_attend(p["attn"], cfg, h, positions,
-                                           window=window, cache=cache,
+                                           cache=cache, layer=layer,
                                            cache_len=cache_len)
+        elif cache is None:
+            a, new_cache = attn.gqa_attend(p["attn"], cfg, h, positions,
+                                           window=window)
+        else:
+            a, new_cache = attn.gqa_attend_stacked(
+                p["attn"], cfg, h, positions, cache=cache, layer=layer,
+                window=window, cache_len=cache_len)
         x = x + a
         h = rms_norm(x, p["ln_ffn"], cfg.norm_eps)
         if "moe" in p:
@@ -153,7 +159,13 @@ class DecoderLM:
         return x + f, new_cache
 
     def _run_stack(self, stacked: dict, x, positions, *, window: int,
-                   caches=None, cache_len=0, remat=True):
+                   caches=None, first=0, cache_len=0, remat=True):
+        """The layers of ``stacked`` in a scan. ``caches`` = (base,
+        tail) is a stacked cache whose layers ``first, first + 1, ...``
+        are these (see ``attention.gqa_attend_stacked``). The part the
+        layers write (the tail where there is one, else base) rides in
+        the scan's carry, so each layer writes only its new rows into it,
+        in place; the other part is only read."""
         cfg = self.cfg
 
         from repro.models.attention import seq_parallel_degree
@@ -173,15 +185,6 @@ class DecoderLM:
                 return shard_activation(xc, DP, TP, None)
             return _shard(xc, DP, None, None)
 
-        def body(carry, layer):
-            xc = carry
-            p, cache = layer
-            xc = constrain(xc)
-            out, new_cache = self._block(p, cfg, xc, positions,
-                                         window=window, cache=cache,
-                                         cache_len=cache_len)
-            return out, new_cache
-
         if caches is None:
             def body_nc(carry, p):
                 out, _ = _remat(
@@ -191,8 +194,23 @@ class DecoderLM:
                 return out, None
             x, _ = jax.lax.scan(body_nc, x, stacked)
             return x, None
-        x, new_caches = jax.lax.scan(body, x, (stacked, caches))
-        return x, new_caches
+        base, tail = caches
+        has_tail = tail is not None
+
+        def body(carry, layer):
+            xc, written = carry
+            p, i = layer
+            cache = (base, written) if has_tail else (written, None)
+            out, (b, t) = self._block(p, cfg, constrain(xc), positions,
+                                      window=window, cache=cache, layer=i,
+                                      cache_len=cache_len)
+            return (out, t if has_tail else b), None
+
+        layers = first + jnp.arange(jax.tree.leaves(stacked)[0].shape[0],
+                                    dtype=jnp.int32)
+        (x, written), _ = jax.lax.scan(
+            body, (x, tail if has_tail else base), (stacked, layers))
+        return x, (base, written) if has_tail else (written, None)
 
     def _embed_tokens(self, params, tokens, extra_embeds=None):
         cfg = self.cfg
@@ -262,38 +280,29 @@ class DecoderLM:
         def slice_stack(tree, lo, hi):
             return jax.tree.map(lambda a: a[lo:hi], tree)
 
-        new_loc, new_glob = [], []
+        c_loc = caches["local"] if caches else None
+        c_glob = caches["global"] if caches else None
         li = gi = 0
         for g in range(n_groups):
             lp = slice_stack(params["local_blocks"], li, li + loc_per_group)
-            lc = (jax.tree.map(lambda a: a[li: li + loc_per_group],
-                               caches["local"]) if caches else None)
-            x, nlc = self._run_stack(lp, x, positions,
-                                     window=cfg.sliding_window, caches=lc,
-                                     cache_len=cache_len, remat=remat)
+            x, c_loc = self._run_stack(lp, x, positions,
+                                       window=cfg.sliding_window,
+                                       caches=c_loc, first=li,
+                                       cache_len=cache_len, remat=remat)
             gp = slice_stack(params["global_blocks"], gi, gi + 1)
-            gc = (jax.tree.map(lambda a: a[gi: gi + 1], caches["global"])
-                  if caches else None)
-            x, ngc = self._run_stack(gp, x, positions, window=0, caches=gc,
-                                     cache_len=cache_len, remat=remat)
+            x, c_glob = self._run_stack(gp, x, positions, window=0,
+                                        caches=c_glob, first=gi,
+                                        cache_len=cache_len, remat=remat)
             li += loc_per_group
             gi += 1
-            if caches is not None:
-                new_loc.append(nlc)
-                new_glob.append(ngc)
         if tail:
             lp = slice_stack(params["local_blocks"], li, li + tail)
-            lc = (jax.tree.map(lambda a: a[li: li + tail], caches["local"])
-                  if caches else None)
-            x, nlc = self._run_stack(lp, x, positions,
-                                     window=cfg.sliding_window, caches=lc,
-                                     cache_len=cache_len, remat=remat)
-            if caches is not None:
-                new_loc.append(nlc)
+            x, c_loc = self._run_stack(lp, x, positions,
+                                       window=cfg.sliding_window,
+                                       caches=c_loc, first=li,
+                                       cache_len=cache_len, remat=remat)
         if caches is not None:
-            cat = lambda parts: jax.tree.map(
-                lambda *xs: jnp.concatenate(xs, axis=0), *parts)
-            return x, {"local": cat(new_loc), "global": cat(new_glob)}
+            return x, {"local": c_loc, "global": c_glob}
         return x
 
     # --- loss --------------------------------------------------------------
@@ -352,28 +361,27 @@ class DecoderLM:
     def init_cache(self, batch: int, max_len: int, abstract: bool = False):
         return materialize_cache(self.cache_defs(batch, max_len), abstract)
 
-    def _cache_tuple(self, c):
-        cfg = self.cfg
-        if cfg.attention == "mla":
-            return c["c"]
-        return (c["k"], c["v"])
-
     # prefill / decode ------------------------------------------------------
     def prefill(self, params: dict, tokens: jax.Array, cache,
                 extra_embeds: Optional[jax.Array] = None):
+        """Writes the prompt's rows into ``cache`` and returns it with
+        fresh tails (:meth:`_with_tails`), any it had dropped."""
         cfg = self.cfg
         x = self._embed_tokens(params, tokens, extra_embeds)
         positions = jnp.arange(tokens.shape[1])[None, :]
-        caches = jax.tree.map(lambda a: a, cache)
+        bases = {k: (base, None) for k, (base, _) in
+                 self._unwrap(cache).items()}
         x, new_caches = self._apply_layers(
-            params, x, positions,
-            caches=self._unwrap(caches), cache_len=0, remat=False)
+            params, x, positions, caches=bases, cache_len=0, remat=False)
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-        return self._logits(params, x[:, -1:]), self._wrap(new_caches)
+        return (self._logits(params, x[:, -1:]),
+                self._wrap(self._with_tails(new_caches, tokens.shape[1])))
 
     def decode_step(self, params: dict, token: jax.Array, cache,
                     pos: jax.Array):
-        """token: [B, 1]; pos: scalar int32 — current cache length."""
+        """token: [B, 1]; pos: scalar int32 — current cache length.
+        Writes the token's row into each stack's tail where it has one
+        (after prefill), returning the rest of the cache as given."""
         cfg = self.cfg
         x = params["embed"][token]
         if cfg.tie_embeddings or cfg.name.startswith("gemma"):
@@ -386,23 +394,50 @@ class DecoderLM:
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)
         return self._logits(params, x), self._wrap(new_caches)
 
-    # cache trees are stored as dicts {"k":..., "v":...}/{"c":...}; the
-    # block functions take tuples — translate at the boundary.
+    def _with_tails(self, caches, prompt_len: int):
+        """After prefill, each stack's cache gains a tail of zeros for
+        the positions after the prompt, which decode writes while it
+        only reads base: a step then hands base back as it was given
+        (the engine's step passes it through, uncopied). A rolling
+        window stack keeps none: decode rewrites it whole."""
+        out = {}
+        for key, (base, _) in caches.items():
+            s = jax.tree.leaves(base)[0].shape[2]
+            rolling = key == "local" and s == self.cfg.sliding_window
+            tail = None
+            if prompt_len < s and not rolling:
+                tail = jax.tree.map(
+                    lambda a: jnp.zeros(a.shape[:2] + (s - prompt_len,)
+                                        + a.shape[3:], a.dtype), base)
+            out[key] = (base, tail)
+        return out
+
+    # cache trees are stored as dicts {"k", "v"[, "k_tail", "v_tail"]} /
+    # {"c"[, "c_tail"]}; the block functions take (base, tail) tuples —
+    # translate at the boundary.
     def _unwrap(self, cache):
-        cfg = self.cfg
-        def conv(c):
-            if cfg.attention == "mla":
-                return c["c"]
-            return (c["k"], c["v"])
-        return {k: conv(v) for k, v in cache.items()}
+        names = ("c",) if self.cfg.attention == "mla" else ("k", "v")
+
+        def part(c, suffix):
+            if names[0] + suffix not in c:
+                return None
+            parts = tuple(c[n + suffix] for n in names)
+            return parts[0] if len(parts) == 1 else parts
+
+        return {k: (part(c, ""), part(c, "_tail")) for k, c in cache.items()}
 
     def _wrap(self, caches):
-        cfg = self.cfg
-        def conv(c):
-            if cfg.attention == "mla":
-                return {"c": c}
-            return {"k": c[0], "v": c[1]}
-        return {k: conv(v) for k, v in caches.items()}
+        names = ("c",) if self.cfg.attention == "mla" else ("k", "v")
+
+        def conv(base, tail):
+            out = {}
+            for suffix, part in (("", base), ("_tail", tail)):
+                if part is not None:
+                    parts = part if isinstance(part, tuple) else (part,)
+                    out.update({n + suffix: a for n, a in zip(names, parts)})
+            return out
+
+        return {k: conv(*c) for k, c in caches.items()}
 
 
 @dataclasses.dataclass(frozen=True)

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from functools import partial
 from typing import Any, Callable, Optional, Sequence
 
 import jax
@@ -55,17 +56,31 @@ def jit_steps(model) -> tuple[Callable, Callable]:
     """The engine's jitted ``(prefill, decode_step)`` for ``model``.
 
     Both run on the device their committed arguments live on, so one
-    pair serves every chip a bundle is placed on.
+    pair serves every chip a bundle is placed on. Prefill consumes the
+    cache it is given (donated, its rows written in place): rebind the
+    one it returns. Decode consumes nothing. The cache arrays its step
+    returns unchanged (all but the rows it writes, where the model keeps
+    those apart) come back as the very arrays given, not as copies.
+    ``decode.lower`` lowers the jitted step, for ahead-of-time compiles.
     """
-    @jax.jit
+    @partial(jax.jit, donate_argnums=(2,))
     def prefill_fn(params, tokens, cache):
         return model.prefill(params, tokens, cache)
 
     @jax.jit
     def decode_fn(params, token, cache, pos):
-        return model.decode_step(params, token, cache, pos)
+        logits, new = model.decode_step(params, token, cache, pos)
+        # an array returned as it was given leaves the program as None
+        return logits, jax.tree.map(lambda n, c: None if n is c else n,
+                                    new, cache)
 
-    return prefill_fn, decode_fn
+    def decode(params, token, cache, pos):
+        logits, new = decode_fn(params, token, cache, pos)
+        return logits, jax.tree.map(lambda n, c: c if n is None else n,
+                                    new, cache, is_leaf=lambda n: n is None)
+
+    decode.lower = decode_fn.lower
+    return prefill_fn, decode
 
 
 @dataclasses.dataclass
@@ -144,8 +159,8 @@ class ChipWeights:
 @dataclasses.dataclass
 class VirtualDevice:
     """One scheduling unit bound to a chip (``device``): holds at most
-    one resident model's params, committed to that chip, plus saved
-    prefix caches keyed by (group, model)."""
+    one resident model's params, committed to that chip, plus a marker
+    for each prefix it has kept, keyed by (group, model, queries)."""
     did: int
     device: jax.Device
     weights: ChipWeights
@@ -347,9 +362,9 @@ class ServingEngine:
                 q0 += nq
                 cache_key = (stage.prefix_group, stage.model, nq)
                 # prefix reuse is emulated at the bookkeeping level: a
-                # saved cache marks the hit (κ state the scheduler
+                # saved marker records the hit (κ state the scheduler
                 # scored for), but prefill below always starts fresh —
-                # replaying the saved KV would need per-query prefix
+                # replaying a saved KV would need per-query prefix
                 # alignment the tiny-model substrate doesn't model.
                 if (stage.cache_reuse and stage.prefix_group is not None
                         and cache_key in dev.prefix_caches):
@@ -359,7 +374,7 @@ class ServingEngine:
             with span("fate.stage.prefill"):
                 logits, kv = bundle.prefill(dev.params, shard, fresh)
                 if stage.keep_cache and stage.prefix_group is not None:
-                    dev.prefix_caches[cache_key] = kv
+                    dev.prefix_caches[cache_key] = True
                 tok = jnp.argmax(logits[:, -1:], -1).astype(jnp.int32)
             gen = [tok]
             pos = shard.shape[1]

@@ -214,6 +214,29 @@ def test_run_stage_returns_ready_tokens(bundles):
     assert res.wall_s > 0.0
 
 
+def test_kept_prefix_hits_on_rerun_and_holds_no_consumed_cache(bundles):
+    """A stage that keeps its prefix, run twice on one virtual device:
+    the rerun records a hit for all its queries. The device keeps a
+    marker, not a cache that would pin its HBM (prefill starts fresh
+    either way); no buffer a step consumed is read again, and both runs
+    give the same tokens."""
+    from repro.core.planner import Placement
+
+    wf = _workflow()
+    stage = wf.stages["retrieve"]
+    engine = ServingEngine(bundles, n_devices=1, gen_len=3, prompt_len=8)
+    prompts = jax.random.randint(jax.random.PRNGKey(6), (4, 8), 0, 256)
+    where = Placement(wf.wid, stage.sid, (0,), (4,))
+    first = engine.run_stage(wf, stage, where, prompts)
+    again = engine.run_stage(wf, stage, where, prompts)
+    assert not first.prefix_hit and first.prefix_fraction == 0.0
+    assert again.prefix_hit and again.prefix_fraction == 1.0
+    kept = engine.devices[0].prefix_caches
+    assert list(kept) == [("ctx", "qwen-7b", 4)]
+    assert not any(isinstance(x, jax.Array) for x in kept.values())
+    assert bool(jnp.all(first.tokens_out == again.tokens_out))
+
+
 def test_run_workflow_raises_on_empty_plan(bundles):
     class NoPlan:
         def plan(self, wf, state, ready):

@@ -4,13 +4,17 @@ The TPU compiler refuses what interpret mode and the CPU backend accept
 (tiling rules, primitives with no Mosaic lowering, programs that do not
 fit HBM), so the served step functions at full qwen3-1.7b width and the
 Pallas kernels at real widths are compiled here for one v5e chip.
-Nothing runs: these tests say nothing about results or times.
+Nothing runs: these tests say nothing about results or times, but the
+compiled program's text and memory analysis show how it uses HBM.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and every test
 worker imports this file.
 """
+import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -122,3 +126,81 @@ def test_mamba2_scan_compiles(one_chip):
     a_log = _spec(one_chip, (h,), jnp.float32)
     ops.mamba2_scan.lower(xh, bc, bc, dt, a_log, chunk=ssm.chunk,
                           interpret=False).compile()
+
+
+# Qwen1.5-1.8B, as the benchmark serves it: two bundles of it share a chip
+QWEN15_18B = dataclasses.replace(
+    ARCHS["qwen1.5-4b"], name="qwen1.5-1.8b", num_layers=24, d_model=2048,
+    num_heads=16, num_kv_heads=16, head_dim=128, d_ff=5504,
+    rope_theta=1000000.0, norm_eps=1e-6)
+
+_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = (\w+\[[\d,]*\])\S* ([\w-]+)\((.*)$")
+
+
+def _hlo_computations(text: str) -> dict[str, list[tuple]]:
+    """``{computation: [(name, shape, opcode, operands), ...]}`` of an HLO
+    module's text; the entry computation's name starts with ``ENTRY``."""
+    out: dict[str, list[tuple]] = {}
+    cur = None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%(\S+) \(", line)
+        if head:
+            cur = (head.group(1) or "") + head.group(2)
+            out[cur] = []
+        elif cur is not None and (m := _INSTR.match(line)):
+            name, shape, op, rest = m.groups()
+            out[cur].append((name, shape, op,
+                             re.findall(r"%([\w.\-]+)", rest.split(")")[0])))
+    return out
+
+
+def _dims(shape: str) -> tuple[int, ...]:
+    return tuple(int(x) for x in shape[shape.index("[") + 1:-1].split(",")
+                 if x)
+
+
+@pytest.mark.parametrize("batch", [8, 4])
+def test_decode_writes_its_row_in_place(one_chip, batch):
+    """The engine's steps at the benchmark cell's shapes (prompt 128, 8
+    generated tokens). Prefill writes the stacked KV cache in place (it
+    is donated and aliased to the output). Decode, on the cache prefill
+    returns, writes one row per layer into the tail of generated rows,
+    in place, and neither copies nor returns the prompt's stacked cache,
+    nor rewrites a layer's slice of it."""
+    cfg = QWEN15_18B
+    model = build_model(cfg)
+    prefill, decode = jit_steps(model)
+    prompt_len, max_len = 128, 136
+    params = _on(one_chip, jax.eval_shape(model.init,
+                                          jax.random.PRNGKey(0)))
+    cache = _on(one_chip, model.init_cache(batch, max_len, abstract=True))
+    tokens = _spec(one_chip, (batch, prompt_len), jnp.int32)
+    stack = (cfg.num_layers, batch, max_len, cfg.num_kv_heads,
+             cfg.resolved_head_dim)
+    layer_bytes = 2 * 2 * math.prod(stack[1:])          # bf16 K and V
+    tail_bytes = layer_bytes * cfg.num_layers * (max_len - prompt_len) \
+        // max_len
+    mem = prefill.lower(params, tokens, cache).compile().memory_analysis()
+    assert mem.alias_size_in_bytes == cfg.num_layers * layer_bytes
+
+    served = _on(one_chip, jax.eval_shape(prefill, params, tokens, cache)[1])
+    compiled = decode.lower(params, _spec(one_chip, (batch, 1), jnp.int32),
+                            served, _spec(one_chip, (), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes < 2 * tail_bytes    # logits and tail
+    assert mem.temp_size_in_bytes < layer_bytes
+    comps = _hlo_computations(compiled.as_text())
+    updates = []
+    for instrs in comps.values():
+        shapes = {name: shape for name, shape, _, _ in instrs}
+        updates += [(_dims(shape), _dims(shapes[operands[1]]))
+                    for _, shape, op, operands in instrs
+                    if op == "dynamic-update-slice"]
+    assert updates
+    for result, update in updates:
+        assert update[2] == 1, (result, update)
+        assert result not in (stack[1:], (1,) + stack[1:]), (result, update)
+    entry, = (v for k, v in comps.items() if k.startswith("ENTRY"))
+    assert not [n for n, shape, op, _ in entry
+                if op == "copy" and _dims(shape) == stack]
