@@ -26,7 +26,7 @@ QWEN15_4B = ArchConfig(
     name="qwen1.5-4b", family="dense", num_layers=40, d_model=2560,
     num_heads=20, num_kv_heads=20, d_ff=6912, vocab_size=151936,
     attention="gqa", qkv_bias=True, rope_theta=5000000.0,
-    source="hf:Qwen/Qwen1.5-0.5B; hf",
+    source="hf:Qwen/Qwen1.5-4B; hf",
 )
 
 GEMMA3_4B = ArchConfig(
@@ -43,7 +43,7 @@ QWEN3_17B = ArchConfig(
     num_heads=16, num_kv_heads=8, d_ff=6144, vocab_size=151936,
     head_dim=128, attention="gqa", qk_norm=True, rope_theta=1000000.0,
     tie_embeddings=True,
-    source="hf:Qwen/Qwen3-8B; hf",
+    source="hf:Qwen/Qwen3-1.7B; hf",
 )
 
 GRANITE_MOE_3B = ArchConfig(

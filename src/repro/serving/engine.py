@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from collections import Counter
 from functools import partial
 from typing import Any, Callable, Optional, Sequence
 
@@ -125,11 +126,25 @@ class ChipWeights:
     model resident, and dropped when no virtual device on the chip
     still has it resident.  A copy on the chip that already holds
     ``bundle.params`` shares their buffers.
+
+    ``bytes_copied_to`` counts, per ``(model, chip id)``, the bytes of
+    every copy made onto a chip that did not hold the model, and
+    ``bytes_copied`` their total; a copy that shares the home buffers,
+    or that another virtual device on the chip already made, adds
+    nothing.  A ``fate.weights.copy`` span (``model``, ``chip``,
+    ``bytes``) marks each such copy's dispatch: ``jax.device_put`` is
+    asynchronous, so the transfer's time is that of the copy on the
+    device trace, not the span's length.
     """
 
     def __init__(self):
         self._copies: dict[tuple[str, jax.Device], Any] = {}
         self._holders: dict[tuple[str, jax.Device], set[int]] = {}
+        self.bytes_copied_to: Counter[tuple[str, int]] = Counter()
+
+    @property
+    def bytes_copied(self) -> int:
+        return sum(self.bytes_copied_to.values())
 
     def acquire(self, did: int, bundle: ModelBundle,
                 device: jax.Device) -> Any:
@@ -137,7 +152,16 @@ class ChipWeights:
         virtual device ``did``."""
         key = (bundle.name, device)
         if key not in self._copies:
-            self._copies[key] = jax.device_put(bundle.params, device)
+            leaves = jax.tree.leaves(bundle.params)
+            if all(x.devices() == {device} for x in leaves):
+                self._copies[key] = jax.device_put(bundle.params, device)
+            else:
+                nbytes = sum(x.nbytes for x in leaves)
+                with span("fate.weights.copy", model=bundle.name,
+                          chip=device.id, bytes=nbytes):
+                    self._copies[key] = jax.device_put(bundle.params,
+                                                       device)
+                self.bytes_copied_to[(bundle.name, device.id)] += nbytes
         self._holders.setdefault(key, set()).add(did)
         return self._copies[key]
 
@@ -215,6 +239,7 @@ class StageResult:
     prompt_tokens: int = 0          # per query
     output_tokens: int = 0          # per query
     switches: int = 0               # residency switches across shards
+    switch_bytes: int = 0           # weight bytes the switches copied
     prefix_fraction: float = 0.0    # fraction of queries with warm hit
     # per-shard tokens in placement order, each on its device's chip
     shards: tuple[jax.Array, ...] = ()
@@ -344,6 +369,7 @@ class ServingEngine:
                    prompts: jax.Array) -> StageResult:
         bundle = self.models[stage.model]
         t0 = time.perf_counter()
+        copied_before = self.weights.bytes_copied
         n_switches = 0
         hit_queries = 0
         outs = []
@@ -353,7 +379,8 @@ class ServingEngine:
             if nq == 0:
                 continue
             dev = self.devices[did]
-            with span("fate.stage.switch"):
+            with span("fate.stage.switch", model=stage.model, did=did,
+                      chip=dev.device.id):
                 if dev.ensure_resident(bundle,
                                        self._switch_sleep_for(bundle)):
                     n_switches += 1
@@ -401,6 +428,7 @@ class ServingEngine:
             model=stage.model, queries=n_q,
             prompt_tokens=self.prompt_len, output_tokens=self.gen_len,
             switches=n_switches,
+            switch_bytes=self.weights.bytes_copied - copied_before,
             prefix_fraction=hit_queries / n_q if n_q else 0.0,
             shards=tuple(outs))
         self.log.append(res)

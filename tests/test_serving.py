@@ -266,3 +266,95 @@ def test_chip_copy_dropped_after_last_holder_switches(bundles):
     assert d1.ensure_resident(b)
     assert engine.weights.placed() == {("llama-8b", chip)}
     assert d0.params is d1.params
+
+
+def switch_bytes_main() -> None:
+    """In a process with four CPU devices: two Qwen1.5-shaped models of
+    different widths on 8 virtual devices (``d`` on chip ``d % 4``; home
+    is chip 0), served through a fixed sequence of one-stage placements.
+    Prints each stage's ``switch_bytes``, the engine's counters, each
+    model's parameter bytes and the ids of the ``fate.stage.switch`` and
+    ``fate.weights.copy`` spans of a profiler trace as one line of JSON."""
+    import json
+    import tempfile
+
+    from repro.core.planner import Placement
+    from test_spans import fate_spans
+
+    small = SMOKE["qwen1.5-4b"]
+    wide = dataclasses.replace(small, d_model=96, num_heads=6,
+                               num_kv_heads=6, d_ff=192)
+    models = {"qwen-7b": ModelBundle.create("qwen-7b", small, seed=0),
+              "llama-8b": ModelBundle.create("llama-8b", wide, seed=1)}
+    engine = ServingEngine(models, n_devices=8, gen_len=2, prompt_len=8,
+                           chips=jax.devices()[:4])
+    wf = _workflow()
+    prompts = jax.random.randint(jax.random.PRNGKey(9), (4, 8), 0, 256)
+    steps = [("qwen-7b", (0,)),        # home chip: shares the home copy
+             ("qwen-7b", (1,)),        # chip 1 gains a copy
+             ("qwen-7b", (5,)),        # chip 1 already holds it
+             ("qwen-7b", (1, 2)),      # only chip 2 gains a copy
+             ("llama-8b", (1,)),       # chip 1: vd 5 still holds qwen-7b
+             ("llama-8b", (5,)),       # chip 1 holds llama-8b; qwen dropped
+             ("qwen-7b", (1,))]        # released on chip 1, copied again
+    out = []
+    trace_dir = tempfile.mkdtemp()
+    jax.profiler.start_trace(trace_dir)
+    for model, devices in steps:
+        stage = dataclasses.replace(wf.stages["retrieve"], model=model)
+        sizes = (4,) if len(devices) == 1 else (2, 2)
+        res = engine.run_stage(wf, stage, Placement(wf.wid, stage.sid,
+                                                    devices, sizes),
+                               prompts)
+        out.append(res.switch_bytes)
+    jax.profiler.stop_trace()
+    spans = fate_spans(trace_dir)
+    print(json.dumps({
+        "switch_spans": [[s.ids["model"], s.ids["did"], s.ids["chip"]]
+                         for s in spans if s.name == "fate.stage.switch"],
+        "copy_spans": [[s.ids["model"], s.ids["chip"], s.ids["bytes"]]
+                       for s in spans if s.name == "fate.weights.copy"],
+        "stages": out, "total": engine.weights.bytes_copied,
+        "to": [[m, c, n] for (m, c), n in
+               sorted(engine.weights.bytes_copied_to.items())],
+        "model_bytes": {name: sum(x.nbytes for x in
+                                  jax.tree.leaves(b.params))
+                        for name, b in models.items()}}))
+
+
+def test_switch_bytes_count_copies_between_chips():
+    """A switch that copies a model onto a chip other than home counts
+    its parameter bytes; the home chip and a chip that already holds the
+    copy count 0; a copy dropped and made again counts again; the
+    stages' ``switch_bytes`` add up to the engine's total; the spans
+    name what each switch and copy did."""
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    here = Path(__file__).resolve().parent
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join([str(here),
+                                           str(here.parent / "src")]))
+    run = subprocess.run(
+        [sys.executable, "-c",
+         "import test_serving as t; t.switch_bytes_main()"],
+        cwd=here, env=env, capture_output=True, text=True, timeout=600)
+    assert run.returncode == 0, run.stderr[-3000:]
+    got = json.loads(run.stdout.strip().splitlines()[-1])
+    small, wide = got["model_bytes"]["qwen-7b"], got["model_bytes"]["llama-8b"]
+    assert small != wide
+    assert got["stages"] == [0, small, 0, small, wide, 0, small]
+    assert sum(got["stages"]) == got["total"] == 3 * small + wide
+    assert got["to"] == [["llama-8b", 1, wide], ["qwen-7b", 1, 2 * small],
+                         ["qwen-7b", 2, small]]
+    # each switch carries its ids; a copy span wraps each copy made
+    assert got["switch_spans"] == [
+        ["qwen-7b", 0, 0], ["qwen-7b", 1, 1], ["qwen-7b", 5, 1],
+        ["qwen-7b", 1, 1], ["qwen-7b", 2, 2], ["llama-8b", 1, 1],
+        ["llama-8b", 5, 1], ["qwen-7b", 1, 1]]
+    assert got["copy_spans"] == [["qwen-7b", 1, small], ["qwen-7b", 2, small],
+                                 ["llama-8b", 1, wide], ["qwen-7b", 1, small]]
