@@ -20,6 +20,7 @@ from typing import Any, Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.calibration import CalibrationProfile, StageObservation
 from repro.core.costs import CostParams
@@ -240,6 +241,7 @@ class StageResult:
     output_tokens: int = 0          # per query
     switches: int = 0               # residency switches across shards
     switch_bytes: int = 0           # weight bytes the switches copied
+    cache_bytes: int = 0            # fresh KV cache made, over shards
     prefix_fraction: float = 0.0    # fraction of queries with warm hit
     # per-shard tokens in placement order, each on its device's chip
     shards: tuple[jax.Array, ...] = ()
@@ -279,7 +281,10 @@ class ServingEngine:
     (default ``jax.devices()``): on one chip every virtual device
     shares it, on several the placements spread over them.  A shard
     runs on its virtual device's chip, with its prompts, cache and
-    the resident params committed there.
+    the resident params committed there. Every array a shard reads is
+    made on its chip or sent from the host: the fresh cache is filled
+    on the shard's chip, never on another and copied over, and the
+    decode positions are host scalars.
     """
 
     def __init__(self, models: dict[str, ModelBundle], n_devices: int,
@@ -372,6 +377,7 @@ class ServingEngine:
         copied_before = self.weights.bytes_copied
         n_switches = 0
         hit_queries = 0
+        cache_bytes = 0
         outs = []
         q0 = 0
         max_len = self.prompt_len + self.gen_len
@@ -384,7 +390,11 @@ class ServingEngine:
                 if dev.ensure_resident(bundle,
                                        self._switch_sleep_for(bundle)):
                     n_switches += 1
-            with span("fate.stage.put"):
+            nbytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(
+                bundle.model.init_cache(nq, max_len, abstract=True)))
+            cache_bytes += nbytes
+            with span("fate.stage.put", chip=dev.device.id,
+                      cache_bytes=nbytes):
                 shard = jax.device_put(prompts[q0: q0 + nq], dev.device)
                 q0 += nq
                 cache_key = (stage.prefix_group, stage.model, nq)
@@ -396,8 +406,11 @@ class ServingEngine:
                 if (stage.cache_reuse and stage.prefix_group is not None
                         and cache_key in dev.prefix_caches):
                     hit_queries += nq
-                fresh = jax.device_put(bundle.model.init_cache(nq, max_len),
-                                       dev.device)
+                # filled op by op on the shard's chip; the put commits
+                # it there and copies nothing
+                with jax.default_device(dev.device):
+                    fresh = jax.device_put(
+                        bundle.model.init_cache(nq, max_len), dev.device)
             with span("fate.stage.prefill"):
                 logits, kv = bundle.prefill(dev.params, shard, fresh)
                 if stage.keep_cache and stage.prefix_group is not None:
@@ -408,7 +421,7 @@ class ServingEngine:
             with span("fate.stage.decode"):
                 for step in range(self.gen_len - 1):
                     logits, kv = bundle.decode(dev.params, tok, kv,
-                                               jnp.int32(pos + step))
+                                               np.int32(pos + step))
                     tok = jnp.argmax(logits, -1).astype(jnp.int32)
                     gen.append(tok)
             outs.append(jnp.concatenate(gen, axis=1))
@@ -429,6 +442,7 @@ class ServingEngine:
             prompt_tokens=self.prompt_len, output_tokens=self.gen_len,
             switches=n_switches,
             switch_bytes=self.weights.bytes_copied - copied_before,
+            cache_bytes=cache_bytes,
             prefix_fraction=hit_queries / n_q if n_q else 0.0,
             shards=tuple(outs))
         self.log.append(res)

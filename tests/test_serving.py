@@ -358,3 +358,123 @@ def test_switch_bytes_count_copies_between_chips():
         ["llama-8b", 5, 1], ["qwen-7b", 1, 1]]
     assert got["copy_spans"] == [["qwen-7b", 1, small], ["qwen-7b", 2, small],
                                  ["llama-8b", 1, wide], ["qwen-7b", 1, small]]
+
+
+def fresh_cache_main() -> None:
+    """In a process with four CPU devices: one Qwen1.5-shaped model on
+    4 virtual devices (``d`` on chip ``d``; home is chip 0), one stage
+    run on chip 2, the same stage on chip 0, then one split over chips 2
+    and 0. ``jax.device_put`` and the bundle's steps are wrapped to record
+    where each array a shard reads comes from. Prints one line of JSON."""
+    import json
+
+    from repro.core.planner import Placement
+
+    cfg = SMOKE["qwen1.5-4b"]
+    base = ModelBundle.create("qwen-7b", cfg, seed=0)
+    puts, prefills, decodes = [], [], []
+
+    def ids(devices):
+        return sorted(d.id for d in devices)
+
+    def prefill(params, tokens, cache):
+        prefills.append({
+            "chip": ids(jax.tree.leaves(params)[0].devices()),
+            "cache": [[x.committed, ids(x.devices())]
+                      for x in jax.tree.leaves(cache)]})
+        return base.prefill(params, tokens, cache)
+
+    def decode(params, token, cache, pos):
+        decodes.append({
+            "chip": ids(jax.tree.leaves(params)[0].devices()),
+            "pos": (ids(pos.devices()) if isinstance(pos, jax.Array)
+                    else "host")})
+        return base.decode(params, token, cache, pos)
+
+    real_put = jax.device_put
+
+    def device_put(x, device=None, **kw):
+        to = ([device.id] if isinstance(device, jax.Device)
+              else ids(device.device_set))
+        puts.extend({"shape": list(a.shape), "to": to,
+                     "from": (ids(a.devices()) if isinstance(a, jax.Array)
+                              else "host")}
+                    for a in jax.tree.leaves(x))
+        return real_put(x, device, **kw)
+
+    bundle = dataclasses.replace(base, prefill=prefill, decode=decode)
+    gen_len, prompt_len = 3, 8
+    engine = ServingEngine({"qwen-7b": bundle}, n_devices=4,
+                           gen_len=gen_len, prompt_len=prompt_len,
+                           chips=jax.devices()[:4])
+    wf = _workflow()
+    stage = wf.stages["retrieve"]
+    prompts = jax.random.randint(jax.random.PRNGKey(11), (4, prompt_len),
+                                 0, 256)
+    out = {"stages": []}
+    jax.device_put = device_put
+    try:
+        for devices, sizes in [((2,), (4,)), ((0,), (4,)),
+                               ((2, 0), (1, 3))]:
+            res = engine.run_stage(wf, stage, Placement(wf.wid, stage.sid,
+                                                        devices, sizes),
+                                   prompts)
+            out["stages"].append({"sizes": list(sizes),
+                                  "cache_bytes": res.cache_bytes,
+                                  "tokens": res.tokens_out.tolist()})
+    finally:
+        jax.device_put = real_put
+    item = jnp.dtype(cfg.dtype).itemsize
+    out["kv_bytes_per_query"] = (2 * cfg.num_layers
+                                 * (prompt_len + gen_len)
+                                 * cfg.num_kv_heads * cfg.resolved_head_dim
+                                 * item)
+    out["cache_shapes"] = [list(x.shape) for x in jax.tree.leaves(
+        bundle.model.init_cache(1, prompt_len + gen_len, abstract=True))]
+    out.update(puts=puts, prefills=prefills, decodes=decodes)
+    print(json.dumps(out))
+
+
+def test_fresh_cache_and_positions_made_on_the_shards_chip():
+    """A shard on chip 2 reads nothing made on chip 0: prefill is handed
+    a fresh cache committed to the shard's chip, no ``device_put`` moves a
+    cache-shaped array between chips, and the decode positions come from
+    the host. Its tokens equal those of the same stage on chip 0, and
+    ``cache_bytes`` is K plus V of the shard sizes."""
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    here = Path(__file__).resolve().parent
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join([str(here),
+                                           str(here.parent / "src")]))
+    run = subprocess.run(
+        [sys.executable, "-c",
+         "import test_serving as t; t.fresh_cache_main()"],
+        cwd=here, env=env, capture_output=True, text=True, timeout=600)
+    assert run.returncode == 0, run.stderr[-3000:]
+    got = json.loads(run.stdout.strip().splitlines()[-1])
+    on_2, on_0, split = got["stages"]
+    assert on_2["tokens"] == on_0["tokens"]
+    for st in got["stages"]:
+        assert st["cache_bytes"] == (sum(st["sizes"])
+                                     * got["kv_bytes_per_query"])
+    # shards in order: chip 2, chip 0, then chip 2 and chip 0
+    assert [p["chip"] for p in got["prefills"]] == [[2], [0], [2], [0]]
+    for p in got["prefills"]:
+        assert p["cache"] and all(c == [True, p["chip"]]
+                                  for c in p["cache"])
+    assert len(got["decodes"]) == 4 * 2
+    assert {d["chip"][0] for d in got["decodes"]} == {0, 2}
+    assert all(d["pos"] in ("host", d["chip"]) for d in got["decodes"])
+    cache_dims = [s[:1] + s[2:] for s in got["cache_shapes"]]
+    moved = [p for p in got["puts"]
+             if p["from"] not in ("host", p["to"])
+             and p["shape"][:1] + p["shape"][2:] in cache_dims]
+    assert moved == []
+    # the chip-2 stage's switch copied the weights there: the wrapper saw
+    assert any(p["from"] == [0] and p["to"] == [2] for p in got["puts"])

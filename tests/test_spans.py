@@ -171,3 +171,17 @@ def test_span_adds_its_time_under_the_key_set_inside():
         with span("fate.test", times):     # no key: nothing is added
             raise ValueError
     assert times == before
+
+
+def test_put_spans_carry_the_chip_and_fresh_cache_bytes(traced):
+    engine, _, _, spans = traced
+    chip = engine.devices[0].device.id
+    puts = named(spans, "fate.stage.put")
+    assert puts and all(p.ids["chip"] == chip for p in puts)
+    stages = named(spans, "fate.stage")
+    assert len(stages) == len(engine.log)
+    for st, res in zip(stages, engine.log):
+        made = [p.ids["cache_bytes"] for p in puts if p.inside(st)]
+        assert len(made) == len(res.shards)
+        assert all(n > 0 for n in made)
+        assert sum(made) == res.cache_bytes
