@@ -1,8 +1,8 @@
 PYTHON ?= python
 export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH),)
 
-.PHONY: test test-sched bench-sched calibrate audit docs-check \
-  deprecated-check gateway-smoke check
+.PHONY: test test-sched audit docs-check deprecated-check gateway-smoke \
+  check
 
 test:
 	$(PYTHON) -m pytest -q
@@ -20,18 +20,7 @@ test-sched:
 	  tests/test_scale_stress.py tests/test_multiclass.py \
 	  tests/test_routing.py tests/test_gateway.py \
 	  tests/test_arrival_queue.py tests/test_pools_auto.py \
-	  tests/test_event_stream_live.py
-
-bench-sched:
-	$(PYTHON) -m benchmarks.sched_bench --quick --profile --serve \
-	  --serve-slo --calibrate --chaos --recovery --scale --classes \
-	  --gateway
-
-# Cost-model calibration gate (fit round-trip, >=2x probe-error
-# reduction vs hand-set constants, fixed-profile score-path parity);
-# writes CALIBRATION_profile.json next to BENCH_sched.json.
-calibrate:
-	$(PYTHON) -m benchmarks.sched_bench --quick --calibrate
+	  tests/test_event_stream_live.py tests/test_calibration.py
 
 # Invariant auditor smoke: build a journaled chaos run in a temp dir,
 # kill it mid-flight, restore from snapshot + journal replay, and
@@ -60,27 +49,6 @@ gateway-smoke:
 deprecated-check:
 	$(PYTHON) tools/check_deprecated.py
 
-# CI smoke gate: scheduler tests + planner-throughput regression checks
-# (sched_bench exits nonzero if the vectorized engine drops below the
-# 5x wide-frontier target, if steady-state delta rescoring drops below
-# the 2x guard — PR target 3x — if either engine's placements diverge
-# from the reference path, if the --serve-slo control plane stops
-# beating unconditional admission / loses cold-solve parity, if the
-# --calibrate loop stops recovering coefficients / cutting probe error
-# >= 2x / holding fixed-profile parity, if the --chaos gate stops
-# completing 100% of admitted workflows under the seeded fault script
-# within 2x fault-free makespan with bit-identical replay and
-# empty-plan parity, if the --recovery gate stops restoring a
-# killed journaled run bit-identically with clean invariant audits,
-# or if the --scale gate stops completing 1000 workflows on 64
-# devices with zero invariant violations under the per-event
-# overhead ceiling and single-pool/monolithic parity, or if the
-# --classes gate loses default-class bit-parity, platinum attainment
-# under the weighted multi-class config, the bottom class's bounded-
-# wait completion guarantee, or bit-identical journaled recovery of
-# runs killed mid-preemption, or if the --gateway gate loses
-# single-replica gateway/direct-Scheduler bit-parity, 100% completion
-# under wall-clock Poisson HTTP load, routing-disabled bit-identity,
-# or the routed-cheaper-than-fixed-at-quality-floor contract)
-# + docs + the deprecated-surface gate.
-check: test-sched bench-sched docs-check deprecated-check
+# CI gate: the scheduler tests, the docs gate and the
+# deprecated-surface gate.  Speed is measured on the chip by bench/.
+check: test-sched docs-check deprecated-check

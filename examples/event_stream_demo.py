@@ -24,8 +24,8 @@ from repro.workflowbench.suites import overloaded_serving_trace  # noqa: E402
 def main() -> None:
     """Drive one overloaded trace through the Scheduler lifecycle."""
     config = SchedulerConfig(policy="FATE", slo=SLOConfig())
-    print("config artifact (reproduces this run via sched_bench "
-          "--config):")
+    print("config artifact (SchedulerConfig.from_json reproduces "
+          "this run):")
     print("  " + " | ".join(config.to_json().split("\n")[1:4]))
 
     sched = Scheduler(homogeneous_cluster(6), config)

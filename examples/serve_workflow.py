@@ -8,8 +8,9 @@ and prefix-cache reuse on the serving engine.
 
     PYTHONPATH=src python examples/serve_workflow.py
 
-It runs on JAX's default backend; ``chip_smoke.py`` serves the same
-DAG at full qwen3-1.7b width on a TPU.
+It runs on JAX's default backend.  The benchmark serves the same DAG
+at full Qwen1.5 width on a TPU: ``python3 bench/run.py --workload
+pair.agentic.steady --seed 0 --seconds 40 --trace 0``.
 """
 import dataclasses
 import sys

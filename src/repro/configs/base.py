@@ -3,10 +3,10 @@
 Every assigned architecture gets one module in this package exporting
 ``CONFIG`` (the exact published configuration) and ``SMOKE_CONFIG`` (a
 reduced same-family configuration for CPU smoke tests).  The full
-qwen3-1.7b config is served on a TPU by ``chip_smoke.py`` and
-AOT-compiled for a described v5e by ``tests/test_tpu_compile.py``; the
-others are exercised only via the AOT dry-run (ShapeDtypeStruct — no
-allocation).
+qwen3-1.7b config is AOT-compiled for a described v5e by
+``tests/test_tpu_compile.py``; the others are exercised only via the
+AOT dry-run (ShapeDtypeStruct — no allocation).  The models the
+benchmark serves on the chip are configured under ``bench/configs/``.
 """
 from __future__ import annotations
 

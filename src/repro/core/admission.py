@@ -39,9 +39,10 @@ estimate: the serving executor reports every workflow completion back
 via :meth:`AdmissionController.record_completion`, the corrector folds
 the observed/predicted latency ratio into its EWMA, and every later
 admission probe and deferral re-probe uses the corrected margin.  All
-predicted-vs-observed pairs are kept on ``probe_log`` for the
-``sched_bench --calibrate`` gate
-(:func:`repro.workflowbench.metrics.probe_error_summary`).
+predicted-vs-observed pairs are kept on ``probe_log``, which
+:func:`repro.workflowbench.metrics.probe_error_summary` aggregates
+(``tests/test_calibration.py`` compares the calibrated and hand-set
+probe errors).
 """
 from __future__ import annotations
 
@@ -198,7 +199,8 @@ class ProbeRecord:
     decision, ``margin`` the multiplicative safety factor applied to
     it, and ``observed`` the measured completion latency from that
     decision instant — the evidence stream behind the online probe
-    correction and the ``--calibrate`` benchmark gate.
+    correction and the probe-error tests in
+    ``tests/test_calibration.py``.
     """
     wid: str
     family: str

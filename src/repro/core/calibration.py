@@ -38,8 +38,8 @@ the loop between measured wall times (the instrumented
    completion and fed back into every admission probe and deferral
    re-probe (:mod:`repro.core.admission`).
 
-``benchmarks/sched_bench.py --calibrate`` gates the loop end to end;
-the workflow is documented in ``docs/COSTMODEL.md``.
+``tests/test_calibration.py`` checks the loop end to end; the
+workflow is documented in ``docs/COSTMODEL.md``.
 """
 from __future__ import annotations
 
@@ -216,9 +216,9 @@ class CalibrationProfile:
                   base: Optional[float] = None,
                   source: str = "synthetic-truth") -> "CalibrationProfile":
         """Uniformly-perturbed copy — the synthetic ground truth of the
-        fit round-trip harness (``sched_bench --calibrate``,
-        ``tests/test_calibration.py``): generate a trace from the
-        perturbed profile, fit, and the multipliers must be recovered.
+        fit round-trip harness (``tests/test_calibration.py``):
+        generate a trace from the perturbed profile, fit, and the
+        multipliers must be recovered.
         """
         fams = {}
         for f, c in self.families.items():
@@ -321,8 +321,8 @@ class CalibrationProfile:
 
     # -- serialization ---------------------------------------------------
     def to_json(self) -> str:
-        """Serialize to the versioned JSON document CI archives next to
-        ``BENCH_sched.json``."""
+        """Serialize to the versioned JSON document :meth:`save`
+        writes."""
         return json.dumps({
             "version": self.version,
             "source": self.source,

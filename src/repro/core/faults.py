@@ -17,7 +17,8 @@ reproducibly:
   issue time.  All randomness flows through one ``random.Random(seed)``
   stream and scripted faults are pure functions of ``(wid, sid, t)``,
   so two runs of the same plan over the same trace produce bit-identical
-  event streams (the ``sched_bench --chaos`` replay gate).
+  event streams (``tests/test_faults.py::
+  test_seeded_chaos_replay_bit_identical``).
 * :class:`DeviceHealth` — consecutive-transient-failure counter that
   trips a device into quarantine after ``quarantine_after`` strikes.
 * :class:`TransientStageFailure` — the exception
@@ -26,8 +27,9 @@ reproducibly:
   contract as the simulator.
 
 An EMPTY ``FaultPlan()`` arms the machinery but injects nothing: the
-scheduler's fault paths are strictly additive, and the chaos gate
-asserts that an empty plan reproduces the fault-free run bit-for-bit.
+scheduler's fault paths are strictly additive, and
+``tests/test_faults.py`` asserts that an empty plan reproduces the
+fault-free run bit-for-bit.
 
 See ``docs/FAULTS.md`` for the fault taxonomy and recovery semantics.
 """
@@ -225,7 +227,8 @@ class FaultInjector:
         seeded RNG state, fired targeted failures, and the random-
         failure count.  With :meth:`load_state` this lets a restored
         scheduler consume the exact same fault draws the pre-crash
-        run would have — the determinism the recovery gate asserts."""
+        run would have — the determinism ``tests/test_recovery.py``
+        asserts."""
         version, internal, gauss = self._rng.getstate()
         return {"rng": [version, list(internal), gauss],
                 "fired": sorted(list(k) for k in self._fired),

@@ -13,7 +13,7 @@ Two score-generation paths feed the same exact solver:
   copy-on-write planning overlay;
 * the scalar path (``use_matrix=False``) — the seed's per-(stage,
   slot, device) ``planner_score`` loop, kept as the reference baseline
-  for parity tests and ``benchmarks/sched_bench.py``.
+  for parity tests.
 
 Both produce bit-identical weights, hence identical placements.
 
@@ -130,7 +130,7 @@ class FrontierPlanner:
         self.time_limit = time_limit
         self.use_matrix = use_matrix
         # use_delta=False forces a full matrix rebuild every wave — the
-        # reference for incremental-vs-full parity tests and benchmarks
+        # reference for incremental-vs-full parity tests
         self.use_delta = use_delta
         self.warm_start = warm_start
         # rolling ((wid, sid), slot) -> device hint fed to the next
@@ -146,7 +146,7 @@ class FrontierPlanner:
         self._wave_scores: dict[str, FrontierScores] = {}
         self._max_cached_workflows = 64
         # per-phase host ms, the accumulated ``fate.plan.score`` and
-        # ``fate.plan.solve`` spans (benchmarks --profile)
+        # ``fate.plan.solve`` spans (tests/test_spans.py)
         self.phase_ms = {"full_build": 0.0, "delta_rescore": 0.0,
                          "solve": 0.0}
 

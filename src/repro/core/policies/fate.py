@@ -86,7 +86,7 @@ class FATEPolicy(BasePolicy):
 
     @property
     def phase_ms(self):
-        """Planner per-phase wall-time accumulators (benchmarks)."""
+        """Planner per-phase wall-time accumulators."""
         return self.planner.phase_ms
 
     @property

@@ -176,9 +176,8 @@ class SchedulerConfig:
       artifact reproduces a served deployment.
 
     ``to_json``/``from_json`` round-trip the whole object — including
-    the embedded calibration profile — so a benchmark gate can be
-    reproduced from a single JSON artifact
-    (``benchmarks/sched_bench.py --config``).
+    the embedded calibration profile — so a run can be reproduced
+    from a single JSON artifact.
     """
 
     policy: str = "FATE"
@@ -1478,7 +1477,8 @@ class Scheduler:
         returns — the step's commit point for crash recovery.  With
         ``audit_every=N``, every Nth step additionally runs
         :func:`audit_invariants` and raises :class:`RecoveryError` on
-        any violation (the debug hook the recovery gate uses).
+        any violation (the debug hook the stress and pool tests
+        use).
         """
         progressed = self._step_core()
         # flush even on a quiescent step: the final tick may still have
@@ -2839,7 +2839,7 @@ def audit_invariants(sched: Scheduler) -> list[str]:
 
     Runs against any live, snapshotted-and-restored, or replayed
     scheduler — ``tools/invariant_audit.py`` wraps it as a CLI over
-    archived snapshots, the ``--recovery`` bench gate asserts it on
+    archived snapshots, ``tests/test_recovery.py`` asserts it on
     every restored state, and ``Scheduler(audit_every=N)`` runs it as
     an in-``step()`` debug hook.  Invariants:
 

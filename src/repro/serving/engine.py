@@ -23,35 +23,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.calibration import CalibrationProfile, StageObservation
-from repro.core.costs import CostParams
 from repro.core.faults import FaultInjector, TransientStageFailure
 from repro.core.planner import Placement
 from repro.core.state import ExecutionState
-from repro.core.workflow import (DEFAULT_PROFILES, ModelProfile, Stage,
-                                 Workflow)
+from repro.core.workflow import DEFAULT_PROFILES, Stage, Workflow
 from repro.models.families import build_model
 from repro.spans import span
-
-
-def calibrated_switch_sleep(profile: ModelProfile,
-                            cost_params: Optional[CostParams] = None,
-                            time_scale: float = 1.0) -> float:
-    """Emulated HBM weight-swap duration for one model switch.
-
-    The scheduler prices a switch at ``profile.switch_cost *
-    CostParams.switch_scale`` proxy seconds (see
-    :meth:`repro.core.costs.CostModel.switch_cost`); the emulated sleep
-    uses the SAME constants, shrunk by ``time_scale`` (tiny test models
-    run orders of magnitude faster than the 7–14B profiles the proxy
-    costs describe; 1.0 means real-time parity).  With a loaded
-    :class:`~repro.core.calibration.CalibrationProfile` both sides read
-    one source of truth — the engine derives ``profile`` from the
-    calibration's ``model_profiles()`` and asserts at profile-load time
-    that the planner's execution state carries identical constants
-    (:meth:`ServingEngine.run_workflow`).
-    """
-    p = cost_params or CostParams()
-    return profile.switch_cost * p.switch_scale * time_scale
 
 
 def jit_steps(model) -> tuple[Callable, Callable]:
@@ -145,6 +122,8 @@ class ChipWeights:
 
     @property
     def bytes_copied(self) -> int:
+        """Bytes of every copy made onto a chip that did not hold the
+        model, over all models and chips."""
         return sum(self.bytes_copied_to.values())
 
     def acquire(self, did: int, bundle: ModelBundle,
@@ -193,21 +172,14 @@ class VirtualDevice:
     params: Any = None
     prefix_caches: dict = dataclasses.field(default_factory=dict)
 
-    def ensure_resident(self, bundle: ModelBundle,
-                        switch_sleep: float = 0.0) -> bool:
-        """Returns True if a switch happened.
+    def ensure_resident(self, bundle: ModelBundle) -> bool:
+        """Make ``bundle`` this device's resident model; returns True
+        if a switch happened.
 
-        A residency switch drops incompatible prefix caches, releases
-        the previous model's chip copy and places ``bundle``'s params
-        on this device's chip (see :class:`ChipWeights`).  The HBM
-        weight swap of a full deployment is additionally emulated by
-        ``switch_sleep`` seconds so measured τ reflects switch cost.
-        The default sleep is 0 (tests must stay fast); calibration and
-        measurement runs pass :func:`calibrated_switch_sleep`-derived
-        values, which read the same
-        :class:`~repro.core.calibration.CalibrationProfile` constants
-        the planner prices, so there is no engine/planner constant
-        divergence to reconcile.
+        A switch drops the prefix markers of other models, releases the
+        previous model's chip copy and places ``bundle``'s params on
+        this device's chip (see :class:`ChipWeights`); what a switch
+        costs is that copy, if one is made.
         """
         if self.resident == bundle.name:
             return False
@@ -217,8 +189,6 @@ class VirtualDevice:
             self.weights.release(self.did, self.resident, self.device)
         self.params = self.weights.acquire(self.did, bundle, self.device)
         self.resident = bundle.name
-        if switch_sleep:
-            time.sleep(switch_sleep)
         return True
 
 
@@ -250,19 +220,13 @@ class StageResult:
 class ServingEngine:
     """Executes one workflow's stages per a policy's placements.
 
-    ``switch_sleep`` (seconds) emulates the HBM weight swap uniformly;
-    alternatively ``switch_time_scale`` derives a per-model sleep from
-    the model profiles via :func:`calibrated_switch_sleep`, keeping
-    measured τ consistent with the costs the scheduler planned
-    against.  Both default to off (fast tests).
-
     ``calibration`` loads a
-    :class:`~repro.core.calibration.CalibrationProfile` as the single
-    source of truth for those profiles: the per-model sleeps derive
-    from its fitted switch costs, and :meth:`run_workflow` asserts the
-    execution state's (planner-side) profiles carry the same constants
-    — the engine/planner cost divergence the pre-calibration code
-    documented as a TODO is now a load-time error instead.
+    :class:`~repro.core.calibration.CalibrationProfile`: its model
+    profiles label the logged observations by family, and
+    :meth:`run_workflow` asserts that the execution state the policy
+    plans against carries the same constants, so engine and planner
+    cannot diverge silently.  Without one the hand-set
+    ``DEFAULT_PROFILES`` label the observations.
 
     Every executed stage is appended to ``log`` with its calibration
     features; :meth:`observations` converts the log into the
@@ -289,8 +253,6 @@ class ServingEngine:
 
     def __init__(self, models: dict[str, ModelBundle], n_devices: int,
                  *, gen_len: int = 8, prompt_len: int = 32,
-                 switch_sleep: float = 0.0,
-                 switch_time_scale: float = 0.0,
                  calibration: Optional[CalibrationProfile] = None,
                  faults: Optional[FaultInjector] = None,
                  chips: Optional[Sequence[jax.Device]] = None):
@@ -302,28 +264,15 @@ class ServingEngine:
                         for i in range(n_devices)]
         self.gen_len = gen_len
         self.prompt_len = prompt_len
-        self.switch_sleep = switch_sleep
-        self.switch_time_scale = switch_time_scale
         self.calibration = calibration
         self.faults = faults
         self.n_fault_retries = 0
-        # per-model profiles the emulated sleeps derive from: the
+        # per-model profiles the observations are labelled from: the
         # loaded calibration's fit, or the hand-set defaults
         self._profiles = (calibration.model_profiles()
                           if calibration is not None
                           else dict(DEFAULT_PROFILES))
         self.log: list[StageResult] = []
-
-    def _switch_sleep_for(self, bundle: ModelBundle) -> float:
-        """Per-switch emulation sleep for ``bundle`` (see class doc)."""
-        if self.switch_sleep:
-            return self.switch_sleep
-        if self.switch_time_scale:
-            prof = self._profiles.get(bundle.name)
-            if prof is not None:
-                return calibrated_switch_sleep(
-                    prof, time_scale=self.switch_time_scale)
-        return 0.0
 
     def observations(self) -> list[StageObservation]:
         """Calibration observations for every logged stage execution.
@@ -387,8 +336,7 @@ class ServingEngine:
             dev = self.devices[did]
             with span("fate.stage.switch", model=stage.model, did=did,
                       chip=dev.device.id):
-                if dev.ensure_resident(bundle,
-                                       self._switch_sleep_for(bundle)):
+                if dev.ensure_resident(bundle):
                     n_switches += 1
             nbytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(
                 bundle.model.init_cache(nq, max_len, abstract=True)))
@@ -453,10 +401,12 @@ class ServingEngine:
         """Execute the full DAG: plan with the policy, run stages on
         real devices in dependency order, update real execution state.
 
-        With a loaded calibration profile the execution state the
-        policy plans against must carry the SAME constants the engine
-        emulates — asserted here, at profile-load time, so engine and
-        planner can never silently diverge.
+        The state's clock is the wall time since this call began: once
+        a stage's tokens are ready, its devices' free times,
+        residencies and warm prefixes are written at that time.  With a
+        loaded calibration profile the state's model profiles must
+        carry the profile's constants; this is asserted before the
+        first plan.
         """
         with span("fate.workflow", wid=wf.wid):
             return self._run_workflow(wf, policy, state, prompts)

@@ -26,7 +26,8 @@ recent arrivals is penalized so load drifts toward replicas whose
 probes still admit.  With a single replica the gateway adds no
 scheduling decisions of its own: a POST-then-drain run is
 bit-identical (events, placements, fingerprint) to driving the same
-:class:`Scheduler` directly, which ``sched_bench --gateway`` gates.
+:class:`Scheduler` directly (``tests/test_gateway.py::
+test_single_replica_http_parity_with_direct_scheduler``).
 
 Determinism note: the gateway never steps a replica on submission.
 The clock only advances while a client drains it (``/v1/drain``) or
@@ -63,8 +64,8 @@ def scheduler_fingerprint(sched: Scheduler) -> str:
     (in emission order) plus the sorted issued-run records (stage key,
     devices, shard sizes, routed model, start, finish).  Two runs with
     equal fingerprints made the same decisions at the same times —
-    the equality the single-replica gateway parity gate asserts
-    against a direct :class:`Scheduler` run.
+    the equality ``tests/test_gateway.py`` asserts between a
+    single-replica gateway and a direct :class:`Scheduler` run.
     """
     h = hashlib.sha256()
     for ev in sched.events:

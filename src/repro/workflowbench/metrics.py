@@ -126,8 +126,8 @@ def class_summary(res) -> dict[str, dict]:
 
     * ``slo_attainment`` — SLO-met completions over offered in-class
       arrivals (rejections and fault-failures count against it);
-    * ``completion_rate`` — completed over offered (the bottom-class
-      starvation gate asserts this is 1.0);
+    * ``completion_rate`` — completed over offered
+      (``tests/test_multiclass.py`` asserts the bottom class's is 1.0);
     * ``mean_wait`` / ``max_wait`` — end-to-end makespan
       (finish − arrival, queueing included): the bounded-wait side of
       the anti-starvation guarantee;
@@ -213,8 +213,8 @@ def probe_error_summary(records: Sequence) -> dict[str, float]:
 
     ``median_abs_err`` / ``mean_abs_err`` are over
     ``|margin · predicted − observed|`` seconds — the quantity the
-    online EWMA correction shrinks and the ``sched_bench --calibrate``
-    gate compares against the hand-set-margin baseline.
+    online EWMA correction shrinks, and that ``tests/test_calibration.py``
+    compares against the hand-set-margin baseline.
     ``median_ratio`` is the raw ``observed / predicted`` ratio (what a
     perfectly-converged margin would equal); ``mean_margin`` the
     margins actually applied.
@@ -248,33 +248,3 @@ def mechanism_rates(rows: Iterable[dict]) -> dict[str, float]:
         "model_cont": sum(r["same_model_continuations"]
                           for r in rows) / tot_tasks,
     }
-
-
-def chaos_summary(results: dict) -> dict[str, dict]:
-    """Fault-tolerance summary per labelled serving run.
-
-    ``results`` maps a run label (e.g. ``"fault-free"``, ``"chaos"``)
-    to a :class:`~repro.core.scheduler.ServingResult`.  Each row
-    reports completion accounting (offered / completed / failed /
-    completion rate over admitted work), the horizon, and the fault
-    machinery counters — the quantities the chaos gate asserts on.
-    """
-    out: dict[str, dict] = {}
-    for label, res in results.items():
-        n_completed = len(res.stats)
-        n_admitted = n_completed + len(res.failed)
-        out[label] = {
-            "n_offered": res.n_offered,
-            "n_completed": n_completed,
-            "n_rejected": len(res.rejected),
-            "n_failed": len(res.failed),
-            "completion_rate": (n_completed / n_admitted
-                                if n_admitted else float("nan")),
-            "horizon": res.horizon,
-            "device_downs": res.device_downs,
-            "shard_failures": res.shard_failures,
-            "retries": res.retries,
-            "stragglers": res.stragglers,
-            "speculations": res.speculations,
-        }
-    return out

@@ -192,8 +192,9 @@ def overloaded_serving_trace(n_workflows: int = 18, rate: float = 14.0,
     arrival rate far above the cluster's service rate, so unconditional
     admission drives queueing delay (and P95) unboundedly up while an
     admission controller can trade rejected arrivals for SLO-met
-    goodput.  Used by ``benchmarks/sched_bench.py --serve-slo`` and
-    ``tests/test_admission.py``.
+    goodput.  Used by ``tests/test_admission.py``,
+    ``tests/test_preemption.py`` and the fault, class and calibration
+    suites.
     """
     return poisson_serving_trace(n_workflows=n_workflows, rate=rate,
                                  seed=seed, num_queries=num_queries,
@@ -202,8 +203,8 @@ def overloaded_serving_trace(n_workflows: int = 18, rate: float = 14.0,
 
 def agentic_workflow(wid: str, num_queries: int = 8) -> Workflow:
     """Retrieve -> two workers -> merge over two model families, the
-    DAG the serving engine runs (``examples/serve_workflow.py``,
-    ``chip_smoke.py``).
+    DAG the serving engine runs (``examples/serve_workflow.py``; the
+    benchmark's ``bench/workload.py`` keeps a copy as data).
 
     ``retrieve``/``work_b``/``merge`` share the ``ctx`` prefix group on
     ``qwen-7b`` and ``work_a`` runs on ``llama-8b``, so a plan prices
@@ -269,7 +270,7 @@ def routed_serving_trace(n_workflows: int = 10, rate: float = 4.0,
                          seed: int = 0, num_queries: int = 8
                          ) -> list[tuple[float, "Workflow"]]:
     """Poisson trace of :func:`routed_workflow_instance` copies — the
-    cost/quality routing benchmark input (``sched_bench --gateway``).
+    cost/quality routing input (``tests/test_routing.py``).
 
     Every worker stage prefers the large ``qwen-14b`` family but
     declares cheaper admissible alternates, so a routing-enabled
@@ -371,7 +372,7 @@ def scale_instance(index: int, num_queries: int = 4) -> Workflow:
 def scale_serving_trace(n_workflows: int = 1000, burst: int = 8,
                         gap: float = 0.25, num_queries: int = 4
                         ) -> list[tuple[float, "Workflow"]]:
-    """Bursty arrival trace for the 1k-workflow ``--scale`` gate.
+    """Bursty arrival trace for the pooled, batched-probe scale path.
 
     Arrivals land in bursts of ``burst`` workflows at the SAME
     timestamp (exercising batched admission probing: one shared
@@ -388,7 +389,7 @@ def scale_serving_trace(n_workflows: int = 1000, burst: int = 8,
 
 
 def chaos_fault_plan(seed: int = 0) -> "FaultPlan":
-    """The chaos-gate fault script for the overloaded serving trace.
+    """The chaos fault script for the overloaded serving trace.
 
     A fixed, seeded :class:`~repro.core.faults.FaultPlan` combining
     every fault class the scheduler handles, with timings tuned to the
@@ -404,8 +405,8 @@ def chaos_fault_plan(seed: int = 0) -> "FaultPlan":
       workflow shapes (a prefix-suite worker and a conflict-suite
       level stage), exercising retry-with-backoff.
 
-    Used by ``benchmarks/sched_bench.py --chaos`` and
-    ``tests/test_faults.py``.
+    Used by ``tests/test_faults.py``, ``tests/test_recovery.py`` and
+    ``tests/test_multiclass.py``.
     """
     from repro.core.faults import (DeviceCrash, FaultPlan, ShardFailure,
                                    Slowdown)

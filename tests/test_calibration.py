@@ -14,6 +14,7 @@ from repro.core.devices import heterogeneous_cluster, homogeneous_cluster
 from repro.core.executor import ServingExecutor, fresh_state
 from repro.core.planner import FrontierPlanner
 from repro.core.policies import make_policy
+from repro.core.scheduler import Scheduler, SchedulerConfig
 from repro.core.scoring import ScoreParams, Scorer
 from repro.core.workflow import DEFAULT_PROFILES, Stage, Workflow
 from repro.workflowbench.metrics import probe_error_summary
@@ -267,6 +268,44 @@ def test_online_margin_learns_on_drifting_trace():
     assert s_online["median_abs_err"] <= s_static["median_abs_err"]
 
 
+@pytest.mark.xfail(strict=True, raises=AssertionError, reason=(
+    "the reduction reads 1.62x (hand-set median abs error 2.17 s, "
+    "calibrated 1.34 s) against the 2x target; 2.9x was reported when "
+    "the calibration loop landed, and why it fell is not yet known"))
+def test_calibrated_probe_error_at_least_halves_on_overloaded_trace():
+    """Measure -> fit -> profile -> probe, end to end: in a world that
+    follows the true constants, a scheduler that believes the profile
+    fitted from a noisy synthetic engine trace, with the online margin
+    warmed by one pass, has at most half the median absolute probe
+    error of one that believes the hand-set constants with the static
+    margin, on the overloaded n=18 trace."""
+    truth = _truth()
+    fitted = C.fit_profile(
+        C.synthetic_trace(truth, 600, seed=1, noise=0.01,
+                          time_scale=0.05), time_scale=0.05)
+    trace = overloaded_serving_trace(n_workflows=18, rate=14.0, seed=0,
+                                     num_queries=8)
+
+    def leg(calibration, slo, corrector=None):
+        sched = Scheduler(homogeneous_cluster(6),
+                          SchedulerConfig(policy="FATE", slo=slo,
+                                          calibration=calibration),
+                          world_profiles=truth.model_profiles(),
+                          world_cost_params=truth.cost_params(),
+                          probe_corrector=corrector)
+        for t, wf in trace:
+            sched.submit(wf, at=t)
+        sched.drain()
+        return probe_error_summary(sched.admission.probe_log)
+
+    hand = leg(None, SLOConfig())
+    corrector = C.ProbeCorrector(prior=SLOConfig().probe_margin)
+    for _ in range(2):     # calibration pass + evaluation pass
+        cal = leg(fitted, SLOConfig(online_margin=True), corrector)
+    assert hand["n"] > 0 and cal["n"] > 0
+    assert hand["median_abs_err"] >= 2.0 * cal["median_abs_err"]
+
+
 def test_record_completion_updates_corrector_and_log():
     slo = SLOConfig(online_margin=True)
     adm = AdmissionController(slo)
@@ -307,7 +346,7 @@ def test_probe_family_keying_separates_compositions():
 def test_world_profiles_diverge_executor_from_belief():
     """The executor prices real durations from world_profiles while the
     scheduler's state keeps its believed constants — the mis-belief
-    harness behind the --calibrate probe gate."""
+    harness behind the calibrated probe-error test."""
     truth = _truth()
     trace = overloaded_serving_trace(n_workflows=6, rate=8.0, seed=0,
                                      num_queries=4)

@@ -17,6 +17,7 @@ The contract under test, in order of importance:
   admission probe log respect their configured caps.
 """
 import dataclasses
+import functools
 
 import pytest
 
@@ -31,7 +32,9 @@ from repro.core.scheduler import (DegradedEvent, DeviceDownEvent,
                                   DeviceRecoveredEvent, EventLog,
                                   IssueEvent, RetryEvent, Scheduler,
                                   SchedulerConfig, ShardFailedEvent)
-from repro.workflowbench.suites import poisson_serving_trace
+from repro.workflowbench.suites import (chaos_fault_plan,
+                                        overloaded_serving_trace,
+                                        poisson_serving_trace)
 
 
 def _trace(n=6, seed=3):
@@ -167,6 +170,39 @@ def test_seeded_chaos_replay_bit_identical():
     _, s1 = _run(faults=plan)
     _, s2 = _run(faults=plan)
     assert _events(s1) == _events(s2)
+
+
+@functools.cache
+def _chaos_script_runs():
+    """The seeded chaos script (``chaos_fault_plan``: a crash with
+    recovery, a 3x slowdown, two targeted shard failures) on the first
+    8 arrivals of the overloaded trace, 6 devices: the smallest slice
+    on which every fault class of the script fires.  Returns the
+    trace, the fault-free result and the faulted result."""
+    trace = overloaded_serving_trace(n_workflows=8, rate=14.0, seed=0,
+                                     num_queries=8)
+    base, _ = _run(faults=None, trace=trace, n_devices=6)
+    chaos, _ = _run(faults=chaos_fault_plan(0), trace=trace,
+                    n_devices=6)
+    return trace, base, chaos
+
+
+def test_chaos_script_completes_within_twice_fault_free_horizon():
+    """Every admitted workflow completes under the chaos script, and
+    its horizon stays within 2x the fault-free horizon."""
+    trace, base, chaos = _chaos_script_runs()
+    assert set(chaos.stats) == {wf.wid for _, wf in trace}
+    assert not chaos.failed
+    assert chaos.horizon <= 2.0 * base.horizon
+
+
+def test_chaos_script_engages_every_fault_class():
+    """The script is not vacuous on this trace: a device goes down,
+    both targeted shards fail, and a straggler is detected."""
+    _, _, chaos = _chaos_script_runs()
+    assert chaos.device_downs >= 1
+    assert chaos.shard_failures >= 2
+    assert chaos.stragglers >= 1
 
 
 # ---------------------------------------------------------------------------

@@ -11,6 +11,7 @@ directly.
 import dataclasses
 import http.client
 import json
+import math
 
 from repro.core.devices import homogeneous_cluster
 from repro.core.scheduler import Scheduler, SchedulerConfig, \
@@ -141,6 +142,26 @@ def test_metrics_endpoint_is_read_only():
         doc = json.loads(body)
         assert doc["metrics"]["replicas"][0]["completed"] == 3
         assert doc["metrics"]["slo"]["n_completed"] == 3
+
+
+def test_wall_clock_submissions_over_http_all_complete():
+    """Submissions without ``at`` are stamped on the gateway's own
+    clock, in order; the drained run completes every one of them with
+    a finite P95 latency."""
+    trace = _trace(6)
+    with GatewayServer(_gateway()) as srv:
+        stamps = []
+        for _, wf in trace:
+            status, body = _request(srv.port, "POST", "/v1/workflows",
+                                    {"workflow": wf.to_dict()})
+            assert status == 202
+            stamps.append(json.loads(body)["at"])
+        status, body = _request(srv.port, "POST", "/v1/drain")
+        assert status == 200
+    assert stamps == sorted(stamps) and stamps[0] >= 0.0
+    slo = json.loads(body)["metrics"]["slo"]
+    assert slo["n_completed"] == len(trace)
+    assert math.isfinite(slo["p95_latency"])
 
 
 # -- single-replica bit-parity ------------------------------------------

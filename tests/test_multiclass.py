@@ -11,6 +11,7 @@ replay), and a randomized property suite driving audited multi-class
 runs with snapshot/restore bit-identity checks.
 """
 import dataclasses
+import functools
 import random
 import tempfile
 import time
@@ -32,14 +33,16 @@ from repro.core.scheduler import (Scheduler, SchedulerConfig,
                                   audit_invariants)
 from repro.core.scoring import ScoreParams
 from repro.core.workflow import Stage, Workflow
-from repro.workflowbench.metrics import class_summary
-from repro.workflowbench.suites import (multiclass_overloaded_trace,
+from repro.workflowbench.metrics import class_summary, slo_summary
+from repro.workflowbench.suites import (chaos_fault_plan,
+                                        multiclass_overloaded_trace,
                                         overloaded_serving_trace)
 from test_scale_stress import random_trace
 
 BUDGET_S = 120.0                # per-test wall-clock ceiling
 
-#: The benchmark's weighted two-tier config (``sched_bench --classes``).
+#: The weighted two-tier config: platinum over batch, with aging and
+#: running-shard preemption.
 MC_SLO = dict(
     classes={"platinum": ClassSpec(weight=4.0, latency_scale=8.0),
              "batch": ClassSpec(weight=1.0, latency_scale=40.0,
@@ -381,6 +384,104 @@ def test_journal_replays_shard_preemption_bit_identically():
     assert res.shard_preemptions == base_res.shard_preemptions
     assert res.classes == base_res.classes
     assert _events(restored) == _events(base)
+
+
+@functools.cache
+def _weighted_tier_runs():
+    """The overloaded trace's first 12 arrivals on 6 devices, once
+    class-free under the controlled SLO plane and once tagged
+    platinum/batch/batch under ``MC_SLO``."""
+    cl = homogeneous_cluster(6)
+    single, _ = _run_pairs(
+        overloaded_serving_trace(n_workflows=12, rate=14.0, seed=0,
+                                 num_queries=8), cl, SLOConfig())
+    sched = _run_triples(
+        multiclass_overloaded_trace(n_workflows=12, rate=14.0, seed=0,
+                                    num_queries=8),
+        cl, SLOConfig(**MC_SLO))
+    return single, sched.drain()
+
+
+def test_platinum_attainment_at_least_single_class():
+    """Weighting pays the top class: platinum's SLO attainment under
+    the weighted config is at least the class-free run's overall
+    attainment, with running shards actually killed for it."""
+    single, weighted = _weighted_tier_runs()
+    assert weighted.shard_preemptions > 0
+    assert class_summary(weighted)["platinum"]["slo_attainment"] \
+        >= slo_summary({"single": single})["single"]["slo_attainment"]
+
+
+def test_batch_wait_within_starvation_bound():
+    """Aging bounds the bottom class: batch completes everything, and
+    its longest wait is at most the starvation bound
+    ``(w_platinum - w_batch) / aging_rate`` plus twice the class-free
+    horizon."""
+    single, weighted = _weighted_tier_runs()
+    slo = SLOConfig(**MC_SLO)
+    bound = ((slo.class_weight("platinum") - slo.class_weight("batch"))
+             / slo.aging_rate + 2.0 * single.horizon)
+    batch = class_summary(weighted)["batch"]
+    assert batch["completion_rate"] == 1.0
+    assert batch["max_wait"] <= bound
+
+
+def _mc_chaos_trace():
+    """The overloaded trace's first 10 arrivals, class-tagged: the
+    fewest on which the chaos script fires and a running shard is
+    killed."""
+    return multiclass_overloaded_trace(n_workflows=10, rate=14.0, seed=0,
+                                       num_queries=8)
+
+
+@functools.cache
+def _mc_chaos_baseline():
+    """The weighted config plus the chaos fault script, uninterrupted:
+    its result, its event stream and the indexes of its
+    ``ShardPreemptionEvent``s."""
+    base = _run_triples(_mc_chaos_trace(), homogeneous_cluster(6),
+                        SLOConfig(**MC_SLO), faults=chaos_fault_plan(0))
+    res = base.drain()
+    kills = [i for i, e in enumerate(base.events)
+             if isinstance(e, ShardPreemptionEvent)]
+    return res, _events(base), kills
+
+
+@pytest.mark.parametrize("kill", [0.15, 0.5, 0.85,
+                                  "past_first_preemption"])
+def test_journaled_chaos_multiclass_recovers_at_kill_point(kill):
+    """Weighted classes under the chaos script, journaled (snapshots
+    every 20 steps), killed at a share of the event stream or just past
+    the first running-shard kill, restored cold from snapshot plus
+    journal tail: the drained run is bit-identical to the uninterrupted
+    one and the audits are clean."""
+    base_res, base_events, kills = _mc_chaos_baseline()
+    assert kills, "baseline must preempt a running shard"
+    at = (kills[0] + 1 if kill == "past_first_preemption"
+          else max(1, int(len(base_events) * kill)))
+    with tempfile.TemporaryDirectory() as tmp:
+        journal = EventJournal(tmp, rotate_bytes=64 * 1024)
+        sched = _run_triples(_mc_chaos_trace(), homogeneous_cluster(6),
+                             SLOConfig(**MC_SLO), journal=journal,
+                             faults=chaos_fault_plan(0))
+        journal.write_snapshot(sched.snapshot())
+        steps = 0
+        while sched.events.n_total < at and sched.step():
+            steps += 1
+            if steps % 20 == 0:
+                journal.write_snapshot(sched.snapshot())
+        del sched, journal             # crash: abandon in place
+
+        reopened = EventJournal(tmp)
+        restored = Scheduler.restore(reopened.latest_snapshot(),
+                                     reopened)
+        assert not audit_invariants(restored)
+        res = restored.drain()
+        assert not audit_invariants(restored)
+    assert _result_key(res) == _result_key(base_res)
+    assert res.shard_preemptions == base_res.shard_preemptions
+    assert res.classes == base_res.classes
+    assert _events(restored) == base_events
 
 
 # ---------------------------------------------------------------------------

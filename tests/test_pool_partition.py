@@ -21,55 +21,16 @@ from repro.core.planner import FrontierPlanner
 from repro.core.policies import ALL_POLICIES
 from repro.core.scoring import ScoreParams
 from repro.core.workflow import Stage, Workflow
+from _sched_fixtures import MODELS, bench_workflow, warmed_state
 
-MODELS = ["qwen-7b", "deepseek-7b", "llama-8b", "llama-3b", "qwen-14b"]
 WIDE = (32, 16, 4)              # width, devices, horizon: the repo's
                                 # canonical parity configuration
 
 
-def wide_workflow(width: int = 32, depth: int = 2,
-                  fanout: int = 2) -> Workflow:
-    """Map/reduce DAG with completed ingest parents and fan-out tails
-    (the sched_bench wide-frontier shape, self-contained here)."""
-    stages: dict[str, Stage] = {}
-    for i in range(width):
-        stages[f"in{i}"] = Stage(f"in{i}", MODELS[i % 5],
-                                 base_cost={-1: 0.05},
-                                 output_tokens=256.0)
-        stages[f"w{i}"] = Stage(
-            f"w{i}", MODELS[(i + 1) % 5], max_shards=2,
-            base_cost={-1: 0.1 + 0.01 * (i % 7)},
-            prefix_group=f"g{i % 4}", shared_fraction=0.5,
-            output_tokens=384.0,
-            parents=(f"in{i}", f"in{(i + 1) % width}"))
-        prev = [f"w{i}"]
-        for lv in range(1, depth + 1):
-            cur = []
-            for pi, par in enumerate(prev):
-                for b in range(fanout):
-                    sid = f"c{i}_{lv}_{pi}_{b}"
-                    stages[sid] = Stage(
-                        sid, MODELS[(i + lv + b) % 5],
-                        base_cost={-1: 0.08},
-                        prefix_group=f"g{i % 4}",
-                        output_tokens=256.0, parents=(par,))
-                    cur.append(sid)
-            prev = cur
-    return Workflow(wid=f"pool-wide-{width}", stages=stages,
-                    num_queries=8)
-
-
-def warmed_state(wf: Workflow, width: int, cluster):
-    """Ingest done, models resident, prefixes warm: every scoring term
-    (transfer, locality, prefix, residency) live."""
-    state = fresh_state(cluster)
-    for i in range(width):
-        d = i % cluster.n
-        state.output_loc[(wf.wid, f"in{i}")] = (d,)
-        state.completed.add((wf.wid, f"in{i}"))
-        state.residency[d] = MODELS[i % 5]
-        state.warm_prefix(d, f"g{i % 4}", MODELS[(i + 1) % 5], 8, 0.0)
-    return state
+def wide_workflow(width: int = 32) -> Workflow:
+    """The wide frontier at depth 2, with its own wid."""
+    return bench_workflow(width, depth=2, num_queries=8,
+                          wid=f"pool-wide-{width}")
 
 
 def plan_key(placements):
@@ -258,3 +219,30 @@ def test_fate_pooled_serving_completes_under_audit():
     res = sched.drain()
     assert set(res.stats) == {wf.wid for _, wf in trace}
     assert not res.failed
+
+
+def test_bursty_pooled_batched_trace_completes_under_audit():
+    """The fleet-size configuration at a tier-1 size: bursty
+    same-timestamp arrivals, a 4-pool solve, batched admission probes,
+    a generous SLO and a bounded event ring that slides.  Audited every
+    20 steps and after drain, every workflow completes and nothing is
+    rejected or failed."""
+    from repro.core.admission import SLOConfig
+    from repro.core.scheduler import (Scheduler, SchedulerConfig,
+                                      audit_invariants)
+    from repro.workflowbench.suites import scale_serving_trace
+
+    trace = scale_serving_trace(64, burst=8, gap=2.0, num_queries=1)
+    sched = Scheduler(homogeneous_cluster(16),
+                      SchedulerConfig(policy="FATE",
+                                      slo=SLOConfig(latency_scale=100.0),
+                                      pools=4, batch_probes=True,
+                                      event_buffer=256),
+                      audit_every=20)
+    for t, wf in trace:
+        sched.submit(wf, at=t)
+    res = sched.drain()
+    assert not audit_invariants(sched)
+    assert len(res.stats) == len(trace)
+    assert not res.rejected and not res.failed
+    assert sched.events.n_dropped > 0   # the ring slid

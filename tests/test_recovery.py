@@ -20,6 +20,7 @@ The contract under test, in order of importance:
   schedulers; snapshots are refused for dependency-injected runs.
 """
 import dataclasses
+import functools
 import json
 
 import pytest
@@ -337,6 +338,50 @@ def test_crash_restore_with_journal_replay_is_bit_identical(tmp_path):
     assert _fingerprint(res, restored) == base_fp
     # the journal kept recording through the post-restore drain
     assert reopened.next_index == base_fp["n_events"]
+
+
+@functools.cache
+def _baseline_fingerprint():
+    return _baseline()[0]
+
+
+@pytest.mark.parametrize("frac,torn", [(0.1, False), (0.3, False),
+                                       (0.5, True), (0.7, False),
+                                       (0.9, False)])
+def test_crash_restore_at_swept_kill_points_is_bit_identical(
+        tmp_path, frac, torn):
+    """Kill the journaled chaos run at a swept share of its event
+    stream (snapshots every 20 steps), reopen the journal cold and
+    restore: the drained result is bit-identical to the uninterrupted
+    run and the audits are clean.  One kill point also tears the
+    journal's last line, which the reopen must detect."""
+    base_fp = _baseline_fingerprint()
+    journal = EventJournal(tmp_path, rotate_bytes=64 * 1024)
+    sched = Scheduler(homogeneous_cluster(4), _config(),
+                      journal=journal)
+    for t, wf in _trace():
+        sched.submit(wf, at=t)
+    journal.write_snapshot(sched.snapshot())
+    steps = 0
+    while sched.events.n_total < max(1, int(base_fp["n_events"] * frac)):
+        if not sched.step():
+            break
+        steps += 1
+        if steps % 20 == 0:
+            journal.write_snapshot(sched.snapshot())
+    del sched, journal                   # crash: abandon in place
+    if torn:
+        seg = sorted(tmp_path.glob("events-*.jsonl"))[-1]
+        with seg.open("a") as fh:        # simulated mid-append crash
+            fh.write('{"event_version": 1, "type": "Sta')
+
+    reopened = EventJournal(tmp_path)
+    assert reopened.recovered_torn_tail == torn
+    restored = Scheduler.restore(reopened.latest_snapshot(), reopened)
+    assert audit_invariants(restored) == []
+    res = restored.drain()
+    assert audit_invariants(restored) == []
+    assert _fingerprint(res, restored) == base_fp
 
 
 def test_attach_journal_rejects_misaligned_cursor(tmp_path):

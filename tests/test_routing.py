@@ -26,6 +26,7 @@ from repro.core.workflow import DEFAULT_PROFILES, Stage, Workflow
 from repro.workflowbench.suites import (poisson_serving_trace,
                                         routed_serving_trace,
                                         routed_workflow_instance)
+from _sched_fixtures import bench_workflow, warmed_state
 
 
 def _routed_stage():
@@ -154,20 +155,14 @@ def test_routing_enabled_batch_frontier_candidate_free_parity():
     """32x16 H=4 wide batch frontier: the routed planner over a
     candidate-free workflow produces the exact placements of the
     plain planner."""
-    import sys
-    from pathlib import Path
-    sys.path.insert(0, str(Path(__file__).resolve().parents[1]
-                           / "benchmarks"))
-    from sched_bench import _warmed_state, bench_workflow
-
     wf = bench_workflow(32)
     cluster = heterogeneous_cluster(16)
     ready = [f"w{i}" for i in range(32)]
     params = ScoreParams(horizon=4)
     plain = FrontierPlanner(params).plan(
-        wf, _warmed_state(wf, 32, cluster), list(ready))
+        wf, warmed_state(wf, 32, cluster), list(ready))
     routed = FrontierPlanner(params, routing=RoutingConfig()).plan(
-        wf, _warmed_state(wf, 32, cluster), list(ready))
+        wf, warmed_state(wf, 32, cluster), list(ready))
     assert [(p.sid, p.devices, p.shard_sizes, p.model)
             for p in plain] \
         == [(p.sid, p.devices, p.shard_sizes, p.model)
