@@ -27,11 +27,15 @@ def flash_attention(q, k, v, *, causal=True, window=0, block_q=128,
                                interpret=interpret)
 
 
-@partial(jax.jit, static_argnames=("block_s", "interpret"))
-def decode_attention(q, k_cache, v_cache, cache_len, *, block_s=256,
+@partial(jax.jit, static_argnames=("start", "window", "kv_major",
+                                   "block_s", "interpret"))
+def decode_attention(q, base, tail, new, layer, cache_len, *, start,
+                     window=0, kv_major=False, block_s=None,
                      interpret=False):
-    return _da.decode_attention(q, k_cache, v_cache, cache_len,
-                                block_s=block_s, interpret=interpret)
+    return _da.decode_attention(q, base, tail, new, layer, cache_len,
+                                start=start, window=window,
+                                kv_major=kv_major, block_s=block_s,
+                                interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("block_c", "block_f", "block_d",

@@ -4,9 +4,11 @@ and cache-based decode paths.
 The chunked implementation keeps the materialized score block bounded at
 ``[B, H, q_chunk, kv_chunk]`` regardless of sequence length — this is the
 XLA-path equivalent of the Pallas flash kernel in ``repro.kernels`` and
-is what the models run.  The Pallas kernels are not on the model path
-yet; they are tested against ``repro.kernels.ref`` in interpret mode on
-the CPU and AOT-compiled for a described v5e
+is what the models run.  One Pallas kernel is on the model path: on a
+TPU, a stacked cache's decode attends in one fused pass per layer
+(:func:`decode_attention_stacked`); the jnp parts are its reference and
+serve everywhere else.  The kernels are tested against jnp references in
+interpret mode on the CPU and AOT-compiled for a described v5e
 (``tests/test_tpu_compile.py``).
 """
 from __future__ import annotations
@@ -17,6 +19,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.decode_attention import \
+    decode_attention as fused_decode_attention
 from repro.models.layers import (DP, FSDP, TP, ParamDef, apply_rope,
                                  shard_activation)
 
@@ -196,6 +200,66 @@ def decode_attention_parts(q: jax.Array, parts: list) -> jax.Array:
     return out.reshape(b, 1, h, d).astype(q.dtype)
 
 
+def fused_view(base_shape: tuple, tail_len: int) -> Optional[bool]:
+    """Whether decode over a stack of ``base_shape`` [L, B, S, KV, D] with
+    a tail of ``tail_len`` rows takes the fused pass
+    (:mod:`repro.kernels.decode_attention`) on a TPU: None where it does
+    not, else whether the pass reads the stack KV-major.
+
+    The pass reads the stack in the layout the TPU stores it in, which
+    tiles the two minor dims by 8 rows: [S, KV, D] as it stands where KV
+    is a multiple of 8, else the base as [KV, S, D] where S and the tail
+    are (the TPU then puts S second-minor, so that nothing is padded);
+    the tail it reads as [T, KV, D] either way, so that decode's row
+    write into it fills whole tiles. Elsewhere, with no tail, or with
+    heads narrower than the 128 lanes, the jnp parts serve."""
+    s, kv, d = base_shape[2:]
+    if not tail_len or d % 128:
+        return None
+    if kv % 8 == 0:
+        return False
+    if s % 8 == 0 and tail_len % 8 == 0:
+        return True
+    return None
+
+
+def decode_attention_stacked(q: jax.Array, base: tuple,
+                             tail: Optional[tuple], new: tuple,
+                             layer: jax.Array | int,
+                             cache_len: jax.Array | int, *, start: int,
+                             window: int = 0) -> jax.Array:
+    """Single-token attention of layer ``layer`` of a stacked cache
+    (``base``, ``tail`` as :func:`gqa_attend_stacked` holds them; base
+    rows ``start ..`` are the tail's) plus the token's own ``new`` k/v.
+    On a TPU, where :func:`fused_view` allows, one Pallas pass reads the
+    layer in place; elsewhere :func:`decode_attention_parts` serves, the
+    reference. Positions below ``cache_len`` are live (with ``window``,
+    only the last ``window - 1`` of them)."""
+    def parts(q, base, tail, new, layer, cache_len):
+        def live(pos):
+            ok = pos < cache_len
+            return ok & (pos > cache_len - window) if window else ok
+
+        s_cache = base[0].shape[2]
+        at = jnp.arange(s_cache)
+        ps = [(layer_of(base[0], layer), layer_of(base[1], layer),
+               live(at) & (at < start))]
+        if tail is not None:
+            ps.append((layer_of(tail[0], layer), layer_of(tail[1], layer),
+                       live(start + jnp.arange(s_cache - start))))
+        ps.append((*new, jnp.ones(1, bool)))
+        return decode_attention_parts(q, ps)
+
+    view = fused_view(base[0].shape, 0 if tail is None else tail[0].shape[2])
+    args = (q, base, tail, new, layer, cache_len)
+    if view is None:
+        return parts(*args)
+    return jax.lax.platform_dependent(
+        *args, default=parts, tpu=partial(
+            fused_decode_attention, start=start, window=window,
+            kv_major=view))
+
+
 def put_rows(stack: jax.Array, rows: jax.Array, layer: jax.Array | int,
              pos: jax.Array | int) -> jax.Array:
     """``stack`` [L, B, S, ...] with ``rows`` [B, n, ...] written into
@@ -312,7 +376,7 @@ def gqa_attend_stacked(p: dict, cfg, x: jax.Array, positions: jax.Array, *,
     longer); decode's one row at ``cache_len`` into the tail, or into
     base where there is none. Decode leaves base as it was given where
     it writes the tail. It attends over the cache as it stood plus its
-    own k/v (:func:`decode_attention_parts`). A rolling window base
+    own k/v (:func:`decode_attention_stacked`). A rolling window base
     (S == ``window``, no tail) shifts and rewrites its whole layer.
     """
     sq = x.shape[1]
@@ -333,19 +397,9 @@ def gqa_attend_stacked(p: dict, cfg, x: jax.Array, positions: jax.Array, *,
                                     put_rows(v_base, v_l, layer, 0)), None)
     if sq == 1:
         start = s_cache - (0 if tail is None else tail[0].shape[2])
-
-        def live(pos):
-            ok = pos < cache_len
-            return ok & (pos > cache_len - window) if window else ok
-
-        at = jnp.arange(s_cache)
-        parts = [(layer_of(k_base, layer), layer_of(v_base, layer),
-                  live(at) & (at < start))]
-        if tail is not None:
-            parts.append((layer_of(tail[0], layer), layer_of(tail[1], layer),
-                          live(start + jnp.arange(s_cache - start))))
-        parts.append((k_new, v_new, jnp.ones(1, bool)))
-        out = _proj_out(p, decode_attention_parts(q, parts))
+        out = _proj_out(p, decode_attention_stacked(
+            q, (k_base, v_base), tail, (k_new, v_new), layer, cache_len,
+            start=start, window=window))
         if tail is None:
             return out, ((put_rows(k_base, k_new, layer, cache_len),
                           put_rows(v_base, v_new, layer, cache_len)), None)

@@ -439,6 +439,16 @@ class DecoderLM:
 
         return {k: conv(*c) for k, c in caches.items()}
 
+    def decode_pass(self, cache, platform: str) -> str:
+        """The attention pass this model's decode takes over ``cache`` (as
+        prefill returns it) on a device of ``platform``: "fused" where a
+        stack's decode runs the Pallas pass
+        (:func:`attention.decode_attention_stacked`), else "parts"."""
+        return "fused" if platform == "tpu" and any(
+            isinstance(tail, tuple)
+            and attn.fused_view(base[0].shape, tail[0].shape[2]) is not None
+            for base, tail in self._unwrap(cache).values()) else "parts"
+
 
 @dataclasses.dataclass(frozen=True)
 class CacheLeaf:

@@ -366,7 +366,12 @@ class ServingEngine:
                 tok = jnp.argmax(logits[:, -1:], -1).astype(jnp.int32)
             gen = [tok]
             pos = shard.shape[1]
-            with span("fate.stage.decode"):
+            # a model with no stacked KV cache (RWKV, the hybrids)
+            # attends in no fused pass
+            attn = getattr(bundle.model, "decode_pass", None)
+            with span("fate.stage.decode",
+                      attn=attn(kv, dev.device.platform) if attn
+                      else "parts"):
                 for step in range(self.gen_len - 1):
                     logits, kv = bundle.decode(dev.params, tok, kv,
                                                np.int32(pos + step))

@@ -2,6 +2,7 @@
 oracles across shape/dtype sweeps."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.kernels import ops
@@ -40,11 +41,88 @@ def test_decode_attention(clen, dtype):
     q = jax.random.normal(ks[0], (b, 1, h, d), dtype)
     kc = jax.random.normal(ks[1], (b, s, kv, d), dtype)
     vc = jax.random.normal(ks[2], (b, s, kv, d), dtype)
-    out = ops.decode_attention(q, kc, vc, jnp.int32(clen), interpret=True)
+    # a one-layer stack with no tail and no new row: the plain cache
+    out = ops.decode_attention(q, (kc[None], vc[None]), None, None,
+                               jnp.int32(0), jnp.int32(clen), start=s,
+                               interpret=True)
     ref = R.decode_attention_ref(q, kc, vc, clen)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     assert float(jnp.max(jnp.abs(out.astype(jnp.float32)
                                  - ref.astype(jnp.float32)))) < tol
+
+
+# The fused pass and the jnp parts differ in the order of summation and,
+# where the pass walks more than one block, in the sum each softmax weight
+# is divided by before it is rounded to bf16 (the running one), then round
+# the same f32 result to bf16: one bf16 rounding step (2**-7 of the value,
+# or of 1 near zero) bounds the difference.
+PARTS_TOL = 2.0 ** -7
+
+
+def _against_parts(g, s, t, clen, window, kv_major, block_s=None):
+    """One layer of a stacked cache of ``s`` rows (and a tail of ``t``,
+    or none where ``t`` is 0), read in place by the fused pass, against
+    :func:`decode_attention_parts` over the same rows."""
+    from repro.models.attention import decode_attention_parts
+    n_layers, b, kv, d = 3, 2, 2, 128
+    h, layer = kv * g, 1
+    start = s - t
+    ks = jax.random.split(KEY, 7)
+    dt = jnp.bfloat16
+    base = tuple(jax.random.normal(k, (n_layers, b, s, kv, d), dt)
+                 for k in ks[:2])
+    tail = tuple(jax.random.normal(k, (n_layers, b, t, kv, d), dt)
+                 for k in ks[2:4]) if t else None
+    new = tuple(jax.random.normal(k, (b, 1, kv, d), dt) for k in ks[4:6])
+    q = jax.random.normal(ks[6], (b, 1, h, d), dt)
+    out = ops.decode_attention(q, base, tail, new, jnp.int32(layer),
+                               jnp.int32(clen), start=start, window=window,
+                               kv_major=kv_major, block_s=block_s,
+                               interpret=True)
+
+    def live(pos):
+        ok = pos < clen
+        return ok & (pos > clen - window) if window else ok
+
+    pos = jnp.arange(s)
+    parts = [(base[0][layer], base[1][layer], live(pos) & (pos < start))]
+    if t:
+        parts.append((tail[0][layer], tail[1][layer],
+                      live(start + jnp.arange(t))))
+    parts.append((*new, jnp.ones(1, bool)))
+    ref = decode_attention_parts(q, parts)
+    assert out.shape == ref.shape == (b, 1, h, d)
+    np.testing.assert_allclose(out.astype(jnp.float32),
+                               ref.astype(jnp.float32),
+                               rtol=PARTS_TOL, atol=PARTS_TOL)
+
+
+@pytest.mark.parametrize("g", [1, 2])                  # MHA, GQA
+@pytest.mark.parametrize("has_tail", [True, False])
+@pytest.mark.parametrize("at", ["first", "middle", "last"])
+@pytest.mark.parametrize("window", [0, 5])
+@pytest.mark.parametrize("kv_major", [False, True])
+def test_decode_attention_matches_parts(g, has_tail, at, window, kv_major):
+    """Each part one block, with the cache length at the tail's first,
+    middle and last row."""
+    s, t = 24, 8
+    clen = {"first": s - t, "middle": s - t // 2, "last": s - 1}[at]
+    _against_parts(g, s, t if has_tail else 0, clen, window, kv_major)
+
+
+@pytest.mark.parametrize("g", [1, 2])                  # MHA, GQA
+@pytest.mark.parametrize("has_tail", [True, False])
+@pytest.mark.parametrize("block_s", [8, 16])
+@pytest.mark.parametrize("window", [0, 9])
+@pytest.mark.parametrize("kv_major", [False, True])
+def test_decode_attention_blocks(g, has_tail, block_s, window, kv_major):
+    """Parts longer than a block: 28 base rows and a tail of 12, or 40
+    base rows, walked in blocks of 8 or 16, each part's last block
+    running past its rows (past the array's end, in interpret mode rows
+    of NaN, but for 40 rows in blocks of 8); a window across a block's
+    edge."""
+    s, t = 40, 12 if has_tail else 0
+    _against_parts(g, s, t, s - 5, window, kv_major, block_s)
 
 
 @pytest.mark.parametrize("e,c,d,f", [(4, 96, 160, 192), (2, 128, 64, 64),

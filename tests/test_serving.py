@@ -478,3 +478,59 @@ def test_fresh_cache_and_positions_made_on_the_shards_chip():
     assert moved == []
     # the chip-2 stage's switch copied the weights there: the wrapper saw
     assert any(p["from"] == [0] and p["to"] == [2] for p in got["puts"])
+
+
+def _fused_on_the_cpu(monkeypatch):
+    """Decode attends in the fused pass wherever a TPU would take it, run
+    here in Pallas interpret mode."""
+    from repro.kernels.ops import decode_attention
+    from repro.models import attention
+    parts = attention.decode_attention_stacked
+
+    def stacked(q, base, tail, new, layer, cache_len, *, start, window=0):
+        view = attention.fused_view(base[0].shape,
+                                    0 if tail is None else tail[0].shape[2])
+        if view is None:
+            return parts(q, base, tail, new, layer, cache_len, start=start,
+                         window=window)
+        return decode_attention(q, base, tail, new, layer, cache_len,
+                                start=start, window=window, kv_major=view,
+                                interpret=True)
+
+    monkeypatch.setattr(attention, "decode_attention_stacked", stacked)
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(8, 8), (4, 2)])
+def test_fused_decode_gives_the_parts_tokens(monkeypatch, heads, kv_heads):
+    """Eight greedy decode steps of a tiny stack with 128-lane heads give
+    the same tokens on the jnp parts and on the fused pass (in Pallas
+    interpret mode): MHA read as [S, KV, D], and GQA read KV-major. On
+    a TPU the model names its decode's pass "fused"."""
+    import numpy as np
+
+    from repro.models.families import build_model
+    from repro.serving.engine import jit_steps
+    cfg = dataclasses.replace(SMOKE["qwen1.5-4b"], head_dim=128,
+                              num_heads=heads, num_kv_heads=kv_heads)
+    prompt_len, gen_len, batch = 8, 8, 2
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, prompt_len),
+                                0, cfg.vocab_size, jnp.int32)
+
+    def greedy():
+        model = build_model(cfg)
+        prefill, decode = jit_steps(model)
+        params = model.init(jax.random.PRNGKey(0))
+        logits, kv = prefill(params, tokens,
+                             model.init_cache(batch, prompt_len + gen_len))
+        out = [jnp.argmax(logits[:, -1:], -1).astype(jnp.int32)]
+        for step in range(gen_len):
+            logits, kv = decode(params, out[-1], kv,
+                                np.int32(prompt_len + step))
+            out.append(jnp.argmax(logits, -1).astype(jnp.int32))
+        return model.decode_pass(kv, "tpu"), np.concatenate(out, 1)
+
+    parts = greedy()
+    _fused_on_the_cpu(monkeypatch)
+    fused = greedy()
+    assert parts[0] == fused[0] == "fused"
+    np.testing.assert_array_equal(fused[1], parts[1])

@@ -185,3 +185,40 @@ def test_put_spans_carry_the_chip_and_fresh_cache_bytes(traced):
         assert len(made) == len(res.shards)
         assert all(n > 0 for n in made)
         assert sum(made) == res.cache_bytes
+
+
+def test_decode_spans_name_the_attention_pass(traced):
+    """Heads of 16 lanes on the CPU: every decode takes the jnp parts."""
+    decodes = named(traced[3], "fate.stage.decode")
+    assert decodes and all(d.ids["attn"] == "parts" for d in decodes)
+
+
+def test_decode_span_names_the_fused_pass_where_it_serves():
+    """The decode span names the pass the model's decode takes over the
+    cache prefill served, on the chip's platform: the jnp parts on the
+    CPU; on a TPU the fused pass for a stack of 128-lane heads, and the
+    parts for narrower heads."""
+    prompt_len, gen_len, batch = 8, 8, 2
+    for head_dim, on_tpu in ((128, "fused"), (16, "parts")):
+        cfg = dataclasses.replace(SMOKE["qwen1.5-4b"], head_dim=head_dim)
+        bundle = ModelBundle.create("m", cfg, seed=0)
+        engine = ServingEngine({"m": bundle}, n_devices=1, gen_len=gen_len,
+                               prompt_len=prompt_len)
+        wf = agentic_workflow("wf-fused", num_queries=batch)
+        prompts = jax.random.randint(jax.random.PRNGKey(0),
+                                     (batch, prompt_len), 0, 256)
+        trace_dir = tempfile.mkdtemp()
+        jax.profiler.start_trace(trace_dir)
+        try:
+            engine.run_stage(wf, dataclasses.replace(
+                wf.stages["retrieve"], model="m"),
+                Placement(wf.wid, "retrieve", (0,), (batch,)), prompts)
+        finally:
+            jax.profiler.stop_trace()
+        (decode,) = named(fate_spans(trace_dir), "fate.stage.decode")
+        assert decode.ids["attn"] == "parts"
+        served = jax.eval_shape(
+            bundle.prefill, bundle.params, prompts,
+            bundle.model.init_cache(batch, prompt_len + gen_len))[1]
+        assert bundle.model.decode_pass(served, "cpu") == "parts"
+        assert bundle.model.decode_pass(served, "tpu") == on_tpu

@@ -88,12 +88,33 @@ def test_flash_attention_compiles(one_chip, sq):
     ops.flash_attention.lower(q, k, k, interpret=False).compile()
 
 
-def test_decode_attention_compiles(one_chip):
-    b, s, h, kv, d = 8, 2048, 16, 8, 128             # qwen3-1.7b heads
+@pytest.mark.parametrize("layers,s,h,kv,kv_major", [
+    (24, 136, 16, 16, False), (40, 136, 20, 20, True),
+    (28, 2048, 16, 8, False)], ids=["24-16-False", "40-20-True", "qwen3-2048"])
+def test_decode_attention_compiles(one_chip, layers, s, h, kv, kv_major):
+    """The fused decode pass at the benchmark's two widths (Qwen1.5-1.8B,
+    16 KV heads, read as stored; Qwen1.5-4B, 20, base read KV-major), over
+    a stack of 136 rows with a tail of 8, in one block each; and at
+    qwen3-1.7b's heads over 2048 rows, the base in four blocks of 512 (the
+    last running past its 2040 rows). It reads the layer in place, so
+    nothing copies the base. The 4B's tail is read as [T, KV, D], where
+    decode's row write fills whole tiles; from its default layout (T
+    second-minor) it is relaid out once, as at the decode step's entry."""
+    b, t, d = 8, 8, 128
     q = _spec(one_chip, (b, 1, h, d))
-    kc = _spec(one_chip, (b, s, kv, d))
+    base = _spec(one_chip, (layers, b, s, kv, d))
+    tail = _spec(one_chip, (layers, b, t, kv, d))
+    new = _spec(one_chip, (b, 1, kv, d))
     n = _spec(one_chip, (), jnp.int32)
-    ops.decode_attention.lower(q, kc, kc, n, interpret=False).compile()
+    text = ops.decode_attention.lower(
+        q, (base, base), (tail, tail), (new, new), n, n, start=s - t,
+        kv_major=kv_major, interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text
+    stacks = {(layers, b, s, kv, d)} | (set() if kv_major
+                                        else {(layers, b, t, kv, d)})
+    copies = [(n, shape) for instrs in _hlo_computations(text).values()
+              for n, shape, op, _ in instrs if op == "copy"]
+    assert not [c for c in copies if _dims(c[1]) in stacks], copies
 
 
 def test_moe_gemm_compiles(one_chip):
@@ -204,3 +225,51 @@ def test_decode_writes_its_row_in_place(one_chip, batch):
     entry, = (v for k, v in comps.items() if k.startswith("ENTRY"))
     assert not [n for n, shape, op, _ in entry
                 if op == "copy" and _dims(shape) == stack]
+
+
+@pytest.mark.parametrize("cfg,batch", [(QWEN15_18B, 8), (QWEN15_18B, 4),
+                                       (ARCHS["qwen1.5-4b"], 8),
+                                       (ARCHS["qwen1.5-4b"], 4)],
+                         ids=["1.8b-8", "1.8b-4", "4b-8", "4b-4"])
+def test_decode_layer_loop_holds_the_fused_pass(one_chip, cfg, batch):
+    """Decode at both benchmark cells' shapes, on the cache prefill
+    serves (prompt 128, 8 generated tokens): the layer loop attends in
+    one fused pass, and copies no f32 array (the jnp parts relaid their
+    f32 scores out 7 times a layer). The pass reads the prompt's stack
+    in place (the 4B's KV-major, a free view of its layout): no op of the
+    step makes an array of its shape or of one layer's, in either order
+    of S and KV."""
+    model = build_model(cfg)
+    prefill, decode = jit_steps(model)
+    params = _on(one_chip, jax.eval_shape(model.init,
+                                          jax.random.PRNGKey(0)))
+    cache = _on(one_chip, model.init_cache(batch, 136, abstract=True))
+    tokens = _spec(one_chip, (batch, 128), jnp.int32)
+    served = _on(one_chip, jax.eval_shape(prefill, params, tokens, cache)[1])
+    text = decode.lower(params, _spec(one_chip, (batch, 1), jnp.int32),
+                        served, _spec(one_chip, (), jnp.int32)
+                        ).compile().as_text()
+    loops = [instrs for instrs in _hlo_computations(text).values()
+             if any(op == "custom-call" and n.startswith("decode_attention")
+                    for n, _, op, _ in instrs)]
+    assert len(loops) == 1
+    (body,) = loops
+    assert sum(n.startswith("decode_attention") for n, _, _, _ in body) == 1
+    assert not [n for n, shape, op, _ in body
+                if op == "copy" and shape.startswith("f32")]
+    s, kv, d = 136, cfg.num_kv_heads, cfg.resolved_head_dim
+    layer = {(batch, s, kv, d), (batch, kv, s, d)}
+    base = layer | {(n,) + x for x in layer for n in (1, cfg.num_layers)}
+    # an asynchronous copy moves an array between memory spaces (at batch
+    # 4 the compiler prefetches the 1.8B's K or V stack into VMEM, the
+    # bytes the pass reads anyway): it keeps the layout
+    moves = re.findall(r"= \((\w+\[[\d,]*\]\{[^}]*\}), "
+                       r"(\w+\[[\d,]*\]\{[^}]*\}), [^,]*\) copy-start", text)
+    assert len(moves) == text.count(" copy-start(")
+    assert all(re.sub(r"S\(\d+\)", "", a) == re.sub(r"S\(\d+\)", "", b)
+               for a, b in moves), moves
+    views = ("parameter", "get-tuple-element", "bitcast", "copy-done")
+    made = [(n, shape) for instrs in _hlo_computations(text).values()
+            for n, shape, op, _ in instrs
+            if op not in views and _dims(shape) in base]
+    assert not made, made
